@@ -17,8 +17,9 @@ batched/grouped launches, a `templates.BatchedKernelSpec` — names the
 kernel variant (FT level × epilogue chain × dtypes × batch structure),
 `templates.emit` renders it into one Pallas body, and
 `autotune.best_params` picks the tile parameters — memoizing the candidate
-search (`kernels.search`) in a persistent JSON cache,
-``$REPRO_TUNE_CACHE`` or ``~/.cache/repro_tune.json``.
+search (`kernels.search`) in a JSON cache: the writable
+``$REPRO_TUNE_CACHE`` when set, else the committed
+``benchmarks/tuned/<device_kind>.json``, read-only.
 
 Cache keys are ``device/class/caps/bytes/ft_level[/v_variant][/b_N|/g_N]``:
 element width comes from the *actual operand dtype* (bf16 gets its own
@@ -46,12 +47,12 @@ roofline-modeled elsewhere) into ``$REPRO_TUNE_CAMPAIGN_OUT`` and diffs
 the result against the checked-in ``benchmarks/tuned/<device>.json``.
 This benchmark keeps the per-class view: each row reports the
 static-table params next to the autotuned ones (``table=… tuned=…``) so
-table/search divergence is visible per class, and the run re-reads the
-cache file to verify the round trip. Fused-variant rows live in
+table/search divergence is visible per class. Fused-variant rows live in
 `benchmarks.fused_epilogue`; to tune a *new* epilogue (after
 `templates.epilogues.register`) just call ``best_params(m, n, k,
 dtype.itemsize, ft_level=…, spec=your_spec)`` once: the miss searches
-under the variant's working-set model and persists under its own key.
+under the variant's working-set model and stores under its own key
+(written to disk only for a ``$REPRO_TUNE_CACHE`` file).
 """
 from __future__ import annotations
 
@@ -108,9 +109,6 @@ def run() -> None:
              f"padded_x_fixed={r_fixed:.2f} padded_x_table={r_table:.2f} "
              f"padded_x_dispatch={r_disp:.2f} "
              f"predicted_speedup={speedup:.0f}% correct=1")
-    # Persistent-cache round trip: what this run tuned must reload
-    # identically from disk in a fresh cache instance.
-    reloaded = tune_cache.TuneCache(cache.path)
-    assert reloaded.as_dict() == cache.as_dict(), "tuning cache round trip"
     emit("codegen/tune_cache", float("nan"),
-         f"path={cache.path} entries={len(reloaded)} round_trip=1")
+         f"path={cache.path} entries={len(cache)} "
+         f"writable={int(cache.writable)}")

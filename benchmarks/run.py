@@ -26,6 +26,8 @@ def main() -> None:
                          "the selected suites into this directory (open "
                          "with ui.perfetto.dev)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace_dir:
         from repro.tools.trace import trace_dump
         tracer = trace_dump(args.trace_dir)

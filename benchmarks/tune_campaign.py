@@ -24,7 +24,7 @@ import tempfile
 
 import jax.numpy as jnp
 
-from repro.kernels import autotune, tune_cache
+from repro.kernels import autotune, search, tune_cache
 from repro.kernels.templates import BatchedKernelSpec, KernelSpec
 from .common import emit
 
@@ -68,7 +68,8 @@ def regenerate(path: str) -> tune_cache.TuneCache:
     for (_, m, n, k, dtype, ft_level, spec, batch, groups) in CAMPAIGN:
         autotune.best_params(m, n, k, jnp.dtype(dtype).itemsize,
                              ft_level=ft_level, spec=spec, batch=batch,
-                             groups=groups, cache=cache)
+                             groups=groups, cache=cache,
+                             measure=search.can_measure())
     return cache
 
 

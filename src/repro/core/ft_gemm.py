@@ -108,6 +108,28 @@ _ZERO_SUMMARY = lambda: (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32))
 # 2-D core (M,K) @ (K,N)
 # ---------------------------------------------------------------------------
 
+def _abft_body(fn):
+    """Wrap a jnp ABFT body ``fn(ft, spec, a, b, ...)`` so its checksums
+    hold the product to an f32 rounding bound (τ) on the TPU as well:
+
+    * the operands ``a`` and ``b`` are materialized once, behind an
+      optimization barrier. Otherwise XLA may fuse their producers into
+      both the GEMM and the checksum reductions and recompute them there,
+      not always bit for bit, so that the checksums encode operands other
+      than the ones multiplied;
+    * f32 matmuls run at full precision. XLA on TPU rounds f32 matmul
+      operands to bf16 by default (one MXU pass). bf16 operands are exact
+      either way.
+
+    Otherwise rounding alone trips detection and "corrects" clean values."""
+    @functools.wraps(fn)
+    def wrapped(ft, spec, a, b, *rest):
+        a, b = jax.lax.optimization_barrier((a, b))
+        with jax.default_matmul_precision("highest"):
+            return fn(ft, spec, a, b, *rest)
+    return wrapped
+
+
 def _matmul_f32acc(a: jax.Array, b: jax.Array) -> jax.Array:
     return jax.lax.dot_general(
         a, b, (((a.ndim - 1,), (b.ndim - 2,)), ((), ())),
@@ -120,6 +142,7 @@ def _tau(ft: FTConfig, a, b):
     return abft.threshold(a, b, ft.rel_tau)
 
 
+@_abft_body
 def _fused_ft_matmul_2d(ft: FTConfig, spec, a, b, key):
     """Fused online ABFT: checksums from operands, verify, branchless correct."""
     acc = _matmul_f32acc(a, b)                       # (M, N) f32 accumulator
@@ -130,6 +153,7 @@ def _fused_ft_matmul_2d(ft: FTConfig, spec, a, b, key):
     return out.astype(a.dtype), v
 
 
+@_abft_body
 def _nonfused_ft_matmul_2d(ft: FTConfig, spec, a, b, key):
     """Ding-2011-style non-fused ABFT: materialized augmented operands,
     separate passes. optimization_barrier pins the pass structure."""
@@ -424,6 +448,7 @@ def ft_dot_fused(x: jax.Array, w: jax.Array,
 # Batched variant — attention cores (QK^T, PV) and per-expert matmuls
 # ---------------------------------------------------------------------------
 
+@_abft_body
 def _fused_ft_bmm(ft: FTConfig, spec, a, b, key):
     acc = jnp.matmul(a, b, preferred_element_type=jnp.float32)
     ck = abft.product_checksums(a, b)
@@ -546,6 +571,7 @@ def _grouped_dot_jnp(buf, w, gid):
                       ).reshape(t_buf, w.shape[-1])
 
 
+@_abft_body
 def _fused_ft_grouped(ft: FTConfig, spec, buf, w, gid, key):
     """Fused online ABFT for the grouped product on the jnp path: per-group
     checksums via segment reductions, per-group rounding-aware thresholds,
@@ -709,9 +735,16 @@ def _grouped_dw(ft: FTConfig, inject, buf, g_buf, gid, row_end, key=None):
             inject=inject, key=key)
         # Backward-pass corrections are applied but not counted (DESIGN.md).
         return dw
-    # jnp path: per-row-tile outer products segment-summed per group —
-    # exactly the useful FLOPs (T_buf·K·N) — then verified with per-group
-    # checksums (col: (X_g e_K)^T G_g; row: X_g^T (G_g e_N)).
+    return _grouped_dw_jnp(ft, inject, buf, g_buf, gid, ng, bm)
+
+
+@_abft_body
+def _grouped_dw_jnp(ft: FTConfig, inject, buf, g_buf, gid, ng: int, bm: int):
+    """The jnp tgmm: per-row-tile outer products segment-summed per group —
+    exactly the useful FLOPs (T_buf·K·N) — then verified with per-group
+    checksums (col: (X_g e_K)^T G_g; row: X_g^T (G_g e_N))."""
+    k = buf.shape[1]
+    num_tiles = gid.shape[0]
     b3 = buf.reshape(num_tiles, bm, k).astype(jnp.float32)
     g3 = g_buf.reshape(num_tiles, bm, -1).astype(jnp.float32)
     per_tile = jnp.einsum("tbk,tbn->tkn", b3, g3)

@@ -262,8 +262,9 @@ Other modules:
   ops.py      -- dispatching front doors (padding, autotune, interpret)
   ref.py      -- pure-jnp oracles (incl. the unfused epilogue composition)
 
-Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
-validated with interpret=True on CPU.
+Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling). They are
+validated with interpret=True on CPU, compiled for a described TPU v5e by
+tests/test_tpu_compile.py, and run compiled on the chip by chip_smoke.py.
 """
 from . import autotune, grouped, ops, ref, templates
 

@@ -152,15 +152,17 @@ def _pow2_bucket(x: int) -> int:
 
 def best_params(m: int, n: int, k: int, in_bytes: int = 4, *,
                 ft_level: str = "off", spec=None,
-                measure=None, cache=None,
+                measure: bool = False, cache=None,
                 use_cache: bool = True,
                 batch: int = 1, groups: int = 0) -> KernelParams:
     """Autotuned parameter selection: consult the persistent tuning cache
     (keyed by device kind + shape class + element width + FT level + kernel
     variant); on a miss run the candidate search
-    (`kernels.search.select_best` — measured on TPU hardware,
-    roofline-modeled elsewhere), persist the winner, and return it clamped
-    to this concrete problem.
+    (`kernels.search.select_best`, scored by the roofline model), store the
+    winner, and return it clamped to this concrete problem. The request
+    path never measures: only `benchmarks/tune_campaign` passes
+    ``measure=True``, to time candidates on the chip when it regenerates a
+    committed tuning file.
 
     `spec` — optional `templates.KernelSpec`. Fused epilogues shift the
     VMEM budget (aux-operand buffers) and the roofline intensity (aux HBM

@@ -72,6 +72,7 @@ from repro.core.policy import FTConfig, InjectionSpec
 from repro.tools.trace import traced
 from .templates import emit as temit
 from .templates import registry as tregistry
+from .templates.emit import f32dot
 
 F32EPS = float(jnp.finfo(jnp.float32).eps)
 NEG_INF = -1e30
@@ -133,7 +134,7 @@ def _flash_ft_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        rep_ref[...] = jnp.zeros_like(rep_ref)
+        temit.init_report(rep_ref)
 
     q_start = qi * bq
     kv_start = s * bkv
@@ -157,8 +158,9 @@ def _flash_ft_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
     # drawn over the block's LIVE kv span, not the grid extent, so the
     # realized rate matches the nominal one under causal/ragged skipping.
     n_live = _live_kv_steps(true_skv, q_start, bq, bkv, c_off, causal)
-    st_hit, st_step, st_row, st_col = temit.stochastic_seu(
-        rng_ref, SALT_FWD, h * q_blocks + qi, n_live, bq, dh, inject_rate)
+    land_seu = temit.stochastic_hook(
+        rng_ref, SALT_FWD, h * q_blocks + qi, n_live, bq, dh, inject_rate,
+        bit_shift)
 
     @pl.when(run)
     def _step():
@@ -166,10 +168,10 @@ def _flash_ft_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
         k = k_ref[0].astype(jnp.float32)                 # (bkv, dh)
         v = v_ref[0].astype(jnp.float32)
 
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        scores = f32dot(q, k.T)
         if protect_qk:
-            ck_col = jnp.dot(jnp.sum(q, 0, keepdims=True), k.T)   # (1,bkv)
-            ck_row = jnp.dot(q, jnp.sum(k.T, 1, keepdims=True))   # (bq,1)
+            ck_col = f32dot(jnp.sum(q, 0, keepdims=True), k.T)    # (1,bkv)
+            ck_row = f32dot(q, jnp.sum(k.T, 1, keepdims=True))    # (bq,1)
             d_col = jnp.sum(scores, 0, keepdims=True) - ck_col
             d_row = jnp.sum(scores, 1, keepdims=True) - ck_row
             tau_qk = jnp.maximum(
@@ -216,16 +218,15 @@ def _flash_ft_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
         p = jnp.where(good, p, 0.0)
         alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))  # (bq, 1)
 
-        delta = jnp.dot(p, v, preferred_element_type=jnp.float32)  # (bq, dh)
+        delta = f32dot(p, v)  # (bq, dh)
         inj_mask = ((_iota2((bq, dh), 0) == g_row)
                     & (_iota2((bq, dh), 1) == g_col) & hit)
         delta = delta + jnp.where(inj_mask, mag_ref[0], 0.0)
-        delta = temit.apply_seu(delta, st_row, st_col,
-                                st_hit & (st_step == s), bit_shift)
+        delta = land_seu(delta, s)
 
         # ---- fused ABFT on the PV GEMM ------------------------------------
-        ck_col = jnp.dot(jnp.sum(p, 0, keepdims=True), v)          # (1, dh)
-        ck_row = jnp.dot(p, jnp.sum(v, 1, keepdims=True))          # (bq, 1)
+        ck_col = f32dot(jnp.sum(p, 0, keepdims=True), v)           # (1, dh)
+        ck_row = f32dot(p, jnp.sum(v, 1, keepdims=True))           # (bq, 1)
         d_col = jnp.sum(delta, 0, keepdims=True) - ck_col
         d_row = jnp.sum(delta, 1, keepdims=True) - ck_row
         # Rounding-error accumulation stops at the true Skv: on a ragged
@@ -301,7 +302,7 @@ def _flash_decode_kernel(inj_ref, mag_ref, rng_ref, len_ref, tbl_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        rep_ref[...] = jnp.zeros_like(rep_ref)
+        temit.init_report(rep_ref)
 
     true_len = len_ref[slot]
     kv_start = s * page
@@ -311,8 +312,8 @@ def _flash_decode_kernel(inj_ref, mag_ref, rng_ref, len_ref, tbl_ref,
     # slot's LIVE page walk (ceil(len/page)) so the realized rate matches
     # the nominal one across ragged rows.
     n_live = jnp.maximum((true_len + page - 1) // page, 0)
-    st_hit, st_step, st_row, st_col = temit.stochastic_seu(
-        rng_ref, SALT_DECODE, g, n_live, bq, dh, inject_rate)
+    land_seu = temit.stochastic_hook(rng_ref, SALT_DECODE, g, n_live, bq,
+                                     dh, inject_rate, bit_shift)
 
     @pl.when(run)
     def _step():
@@ -320,10 +321,10 @@ def _flash_decode_kernel(inj_ref, mag_ref, rng_ref, len_ref, tbl_ref,
         k = k_ref[0, 0].astype(jnp.float32)               # (page, dh)
         v = v_ref[0, 0].astype(jnp.float32)
 
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        scores = f32dot(q, k.T)
         if protect_qk:
-            ck_col = jnp.dot(jnp.sum(q, 0, keepdims=True), k.T)  # (1,page)
-            ck_row = jnp.dot(q, jnp.sum(k.T, 1, keepdims=True))  # (bq, 1)
+            ck_col = f32dot(jnp.sum(q, 0, keepdims=True), k.T)   # (1,page)
+            ck_row = f32dot(q, jnp.sum(k.T, 1, keepdims=True))   # (bq, 1)
             d_col = jnp.sum(scores, 0, keepdims=True) - ck_col
             d_row = jnp.sum(scores, 1, keepdims=True) - ck_row
             tau_qk = jnp.maximum(
@@ -358,16 +359,15 @@ def _flash_decode_kernel(inj_ref, mag_ref, rng_ref, len_ref, tbl_ref,
         p = jnp.where(good, p, 0.0)
         alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
 
-        delta = jnp.dot(p, v, preferred_element_type=jnp.float32)  # (bq,dh)
+        delta = f32dot(p, v)  # (bq,dh)
         inj_mask = ((_iota2((bq, dh), 0) == g_row)
                     & (_iota2((bq, dh), 1) == g_col) & hit)
         delta = delta + jnp.where(inj_mask, mag_ref[0], 0.0)
-        delta = temit.apply_seu(delta, st_row, st_col,
-                                st_hit & (st_step == s), bit_shift)
+        delta = land_seu(delta, s)
 
         # ---- fused ABFT on the PV GEMM -----------------------------------
-        ck_col = jnp.dot(jnp.sum(p, 0, keepdims=True), v)          # (1, dh)
-        ck_row = jnp.dot(p, jnp.sum(v, 1, keepdims=True))          # (bq, 1)
+        ck_col = f32dot(jnp.sum(p, 0, keepdims=True), v)           # (1, dh)
+        ck_row = f32dot(p, jnp.sum(v, 1, keepdims=True))           # (bq, 1)
         d_col = jnp.sum(delta, 0, keepdims=True) - ck_col
         d_row = jnp.sum(delta, 1, keepdims=True) - ck_row
         # τ follows the row's live span on the final (partial) page, not
@@ -405,11 +405,11 @@ def _recompute_p(q, k, m, linv, *, q_start, kv_start, bq, bkv, true_sq,
     forward. The S = QKᵀ recompute is checksum-verified like the forward's
     (the backward's fifth GEMM). Degenerate rows (l=0 ⇒ linv=0) come out
     exactly zero. Returns (p, scores_scaled, det)."""
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+    scores = f32dot(q, k.T)
     det = jnp.zeros((), bool)
     if protect_qk:
-        ck_col = jnp.dot(jnp.sum(q, 0, keepdims=True), k.T)
-        ck_row = jnp.dot(q, jnp.sum(k.T, 1, keepdims=True))
+        ck_col = f32dot(jnp.sum(q, 0, keepdims=True), k.T)
+        ck_row = f32dot(q, jnp.sum(k.T, 1, keepdims=True))
         d_col = jnp.sum(scores, 0, keepdims=True) - ck_col
         d_row = jnp.sum(scores, 1, keepdims=True) - ck_row
         tau_qk = jnp.maximum(
@@ -435,8 +435,8 @@ def _recompute_p(q, k, m, linv, *, q_start, kv_start, bq, bkv, true_sq,
 def _verify_dp(dp, g, v, rep_ref, *, bq, bkv, dh, rel_tau, corrects,
                q_start, kv_start):
     """Checksum-verify (and correct) the dP = g·Vᵀ product."""
-    ck_col = jnp.dot(jnp.sum(g, 0, keepdims=True), v.T)          # (1, bkv)
-    ck_row = jnp.dot(g, jnp.sum(v, 0, keepdims=True).T)          # (bq, 1)
+    ck_col = f32dot(jnp.sum(g, 0, keepdims=True), v.T)           # (1, bkv)
+    ck_row = f32dot(g, jnp.sum(v, 0, keepdims=True).T)           # (bq, 1)
     d_col = jnp.sum(dp, 0, keepdims=True) - ck_col
     d_row = jnp.sum(dp, 1, keepdims=True) - ck_row
     tau = jnp.maximum(rel_tau * F32EPS * dh * jnp.max(jnp.abs(g))
@@ -457,13 +457,13 @@ def _verify_delta(delta, a, b, eff, rep_ref, *, row_off, rel_tau, corrects,
     materialized transpose). ``eff`` is the live contraction length driving
     the rounding-aware threshold."""
     if transpose_a:
-        ck_col = jax.lax.dot_general(jnp.sum(a, 1, keepdims=True), b,
-                                     _CONTRACT_ROWS)             # (1, n)
-        ck_row = jax.lax.dot_general(a, jnp.sum(b, 1, keepdims=True),
-                                     _CONTRACT_ROWS)             # (m, 1)
+        ck_col = f32dot(jnp.sum(a, 1, keepdims=True), b,
+                        _CONTRACT_ROWS)                          # (1, n)
+        ck_row = f32dot(a, jnp.sum(b, 1, keepdims=True),
+                        _CONTRACT_ROWS)                          # (m, 1)
     else:
-        ck_col = jnp.dot(jnp.sum(a, 0, keepdims=True), b)
-        ck_row = jnp.dot(a, jnp.sum(b, 1, keepdims=True))
+        ck_col = f32dot(jnp.sum(a, 0, keepdims=True), b)
+        ck_row = f32dot(a, jnp.sum(b, 1, keepdims=True))
     d_col = jnp.sum(delta, 0, keepdims=True) - ck_col
     d_row = jnp.sum(delta, 1, keepdims=True) - ck_row
     tau = jnp.maximum(rel_tau * F32EPS * eff * jnp.max(jnp.abs(a))
@@ -502,7 +502,7 @@ def _flash_dq_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
     @pl.when(s == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        rep_ref[...] = jnp.zeros_like(rep_ref)
+        temit.init_report(rep_ref)
 
     true_sq = dims_ref[0]
     true_skv = dims_ref[1]
@@ -517,8 +517,9 @@ def _flash_dq_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
                                                      for i in range(7))
     det_hit = (enable == 1) & (g_h == h) & (g_blk == qi) & (g_s == s)
     n_live = _live_kv_steps(true_skv, q_start, bq, bkv, c_off, causal)
-    st_hit, st_step, st_row, st_col = temit.stochastic_seu(
-        rng_ref, SALT_DQ, h * q_blocks + qi, n_live, bq, dh, inject_rate)
+    land_seu = temit.stochastic_hook(
+        rng_ref, SALT_DQ, h * q_blocks + qi, n_live, bq, dh, inject_rate,
+        bit_shift)
 
     @pl.when(run)
     def _step():
@@ -537,7 +538,7 @@ def _flash_dq_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
             causal=causal, scale=scale, rel_tau=rel_tau, corrects=corrects,
             protect_qk=protect_qk, rep_ref=rep_ref)
 
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)  # (bq,bkv)
+        dp = f32dot(g, v.T)  # (bq,bkv)
         inj_dp = ((_iota2((bq, bkv), 0) == g_row)
                   & (_iota2((bq, bkv), 1) == g_col)
                   & det_hit & (target == BWD_TARGETS["dp_q"]))
@@ -547,13 +548,12 @@ def _flash_dq_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
                         q_start=q_start, kv_start=kv_start)
 
         ds = p * (dp - di) * scale                        # (bq, bkv)
-        delta = jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        delta = f32dot(ds, k)
         inj_dq = ((_iota2((bq, dh), 0) == g_row)
                   & (_iota2((bq, dh), 1) == g_col)
                   & det_hit & (target == BWD_TARGETS["dq"]))
         delta = delta + jnp.where(inj_dq, mag_ref[0], 0.0)
-        delta = temit.apply_seu(delta, st_row, st_col,
-                                st_hit & (st_step == s), bit_shift)
+        delta = land_seu(delta, s)
 
         eff_kv = jnp.minimum(true_skv - kv_start, bkv).astype(jnp.float32)
         delta = _verify_delta(delta, ds, k, eff_kv, rep_ref,
@@ -587,7 +587,7 @@ def _flash_dkv_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
-        rep_ref[...] = jnp.zeros_like(rep_ref)
+        temit.init_report(rep_ref)
 
     true_sq = dims_ref[0]
     true_skv = dims_ref[1]
@@ -611,9 +611,9 @@ def _flash_dkv_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
              else jnp.zeros((), jnp.int32))
     span = jnp.maximum(qi_hi - qi_lo, 0)
     n_live = jnp.where(kv_start < true_skv, n_rep * span, 0)
-    st_hit, st_step, st_row, st_col = temit.stochastic_seu(
+    land_seu = temit.stochastic_hook(
         rng_ref, SALT_DKV, b * kv_blocks + kvi, n_live, bkv, dh,
-        inject_rate)
+        inject_rate, bit_shift)
     step_idx = r * span + (qi - qi_lo)
 
     @pl.when(run)
@@ -633,7 +633,7 @@ def _flash_dkv_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
             causal=causal, scale=scale, rel_tau=rel_tau, corrects=corrects,
             protect_qk=protect_qk, rep_ref=rep_ref)
 
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)  # (bq,bkv)
+        dp = f32dot(g, v.T)  # (bq,bkv)
         inj_dp = ((_iota2((bq, bkv), 0) == g_row)
                   & (_iota2((bq, bkv), 1) == g_col)
                   & det_hit & (target == BWD_TARGETS["dp_kv"]))
@@ -646,14 +646,12 @@ def _flash_dkv_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
             jnp.minimum(true_sq - q_start, bq), 1).astype(jnp.float32)
 
         # ---- dV delta: Pᵀ·g ---------------------------------------------
-        dv_delta = jax.lax.dot_general(p, g, _CONTRACT_ROWS,
-                                       preferred_element_type=jnp.float32)
+        dv_delta = f32dot(p, g, _CONTRACT_ROWS)
         inj_dv = ((_iota2((bkv, dh), 0) == g_row)
                   & (_iota2((bkv, dh), 1) == g_col)
                   & det_hit & (target == BWD_TARGETS["dv"]))
         dv_delta = dv_delta + jnp.where(inj_dv, mag_ref[0], 0.0)
-        dv_delta = temit.apply_seu(dv_delta, st_row, st_col,
-                                   st_hit & (st_step == step_idx), bit_shift)
+        dv_delta = land_seu(dv_delta, step_idx)
         dv_delta = _verify_delta(dv_delta, p, g, eff_q, rep_ref,
                                  row_off=kv_start, rel_tau=rel_tau,
                                  corrects=corrects, transpose_a=True)
@@ -661,8 +659,7 @@ def _flash_dkv_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
 
         # ---- dK delta: dSᵀ·Q --------------------------------------------
         ds = p * (dp - di) * scale                        # (bq, bkv)
-        dk_delta = jax.lax.dot_general(ds, q, _CONTRACT_ROWS,
-                                       preferred_element_type=jnp.float32)
+        dk_delta = f32dot(ds, q, _CONTRACT_ROWS)
         inj_dk = ((_iota2((bkv, dh), 0) == g_row)
                   & (_iota2((bkv, dh), 1) == g_col)
                   & det_hit & (target == BWD_TARGETS["dk"]))

@@ -14,8 +14,8 @@ search for the Pallas GEMM template:
     checksum-update FLOPs for the requested level.
   * `measure_candidates`  — the empirical score: wall-clock timing of each
     instantiated kernel via `benchmarks.common.time_fn` — only meaningful
-    on real hardware, so `select_best` uses it only when the backend is a
-    TPU (or when forced), and otherwise falls back to the model.
+    on real hardware, so `select_best` uses it only when asked
+    (``measure=True``, from `benchmarks/tune_campaign` on the chip).
   * `fit_tile`            — ragged-dispatch helper: the block edge (aligned
     to hardware granularity) that minimizes executed work on a dimension
     that does not divide the class tile, used by the masked kernels.
@@ -274,12 +274,13 @@ def measure_candidates(m: int, n: int, k: int,
 
 def select_best(m: int, n: int, k: int, *, in_bytes: int = 4,
                 ft_level: str = "off", spec=None,
-                measure: Optional[bool] = None,
+                measure: bool = False,
                 max_tile: int = MAX_TILE,
                 candidates: Optional[Sequence[KernelParams]] = None,
                 batch: int = 1, groups: int = 0) -> KernelParams:
-    """The search: enumerate → score (hardware when available, roofline
-    model otherwise) → deterministic winner (ties → larger tiles). The
+    """The search: enumerate → score (hardware timing with ``measure``,
+    the roofline model otherwise) → deterministic winner (ties → larger
+    tiles). The
     measured path times the base 2-D kernel of the requested FT level
     (epilogue chains and the batch axis perturb runtime well under timer
     noise on hardware; the modeled path accounts batch/group counts
@@ -290,8 +291,6 @@ def select_best(m: int, n: int, k: int, *, in_bytes: int = 4,
                                       max_tile=max_tile, groups=groups))
     if not cands:
         raise ValueError(f"no legal tile candidates for {(m, n, k)}")
-    if measure is None:
-        measure = can_measure()
     if measure:
         scores = [t * 1e-6 for t in measure_candidates(
             m, n, k, cands, in_bytes=in_bytes, ft_level=ft_level)]

@@ -52,6 +52,7 @@ reduction axis and flushes per group.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,21 @@ MXU = 128
 
 def _iota2(shape, dim):
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+_MATMUL = (((1,), (0,)), ((), ()))
+
+
+def f32dot(a, b, dims=_MATMUL):
+    """In-kernel dot with f32 accumulation — every GEMM and checksum of the
+    kernel bodies. f32 operands multiply at full f32 precision: ABFT holds
+    a product and its checksums to an f32 rounding bound (τ), and a single
+    MXU pass would first round f32 operands to bf16. bf16 operands are
+    exact either way and keep the single pass."""
+    prec = (jax.lax.Precision.HIGHEST
+            if jnp.float32 in (a.dtype, b.dtype) else None)
+    return jax.lax.dot_general(a, b, dims, precision=prec,
+                               preferred_element_type=jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,10 +138,18 @@ def _locate_correct_full(acc, d_col, d_row, tau, corrects, bm, bn):
     return acc, detected, mag, row, col
 
 
+def init_report(rep_ref):
+    """Zero the report block. The report lives in SMEM, which takes
+    scalar stores only, so each field is written on its own."""
+    z = (0,) * (len(rep_ref.shape) - 1)
+    for f in range(REPORT_WIDTH):
+        rep_ref[z + (f,)] = 0.0
+
+
 def _record(rep_ref, det, mag, row_g, col_g, d_col, d_row, tau, k_elapsed,
             corrects):
-    # The report block is (1, 1, W) for 2-D/grouped launches and
-    # (1, 1, 1, W) for batched ones — index the leading unit dims away.
+    # The report block is an SMEM row of W scalars behind unit leading dims
+    # (`registry._report`) — index those away; every store is a scalar.
     z = (0,) * (len(rep_ref.shape) - 1)
     detf = det.astype(jnp.float32)
     resid = jnp.maximum(jnp.max(jnp.abs(d_col)), jnp.max(jnp.abs(d_row)))
@@ -198,9 +222,12 @@ def stochastic_seu(rng_ref, salt: int, block_uid, n_steps,
                      + jnp.uint32((salt * 0x9E3779B9) & 0xFFFFFFFF)))
     h0 = _mix32(seed ^ (block_uid.astype(jnp.uint32)
                         * jnp.uint32(0x85EBCA6B)))
-    u = (h0 >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24))
+    # Bernoulli(rate) in integers: the top 24 hash bits against
+    # ceil(rate·2^24) (Mosaic has no uint32 -> f32 cast).
+    u24 = (h0 >> jnp.uint32(8)).astype(jnp.int32)
     n_steps = jnp.asarray(n_steps, jnp.int32)
-    hit = (rng_ref[0] == 1) & (u < rate) & (n_steps > 0)
+    hit = ((rng_ref[0] == 1) & (u24 < math.ceil(rate * (1 << 24)))
+           & (n_steps > 0))
     h1, h2, h3 = _mix32(h0 + jnp.uint32(1)), _mix32(h0 + jnp.uint32(2)), \
         _mix32(h0 + jnp.uint32(3))
 
@@ -209,6 +236,20 @@ def stochastic_seu(rng_ref, salt: int, block_uid, n_steps,
                 % jnp.maximum(jnp.asarray(n, jnp.int32), 1))
 
     return hit, _bounded(h1, n_steps), _bounded(h2, bm), _bounded(h3, bn)
+
+
+def stochastic_hook(rng_ref, salt: int, block_uid, n_steps, bm: int,
+                    bn: int, rate: float, bit_shift: int):
+    """`stochastic_seu` + `apply_seu` for one stationary block: returns
+    ``land(delta, step)``, which lands the block's drawn SEU on ``delta``
+    when ``step`` is the drawn one. The gate is the STATIC rate: at rate 0
+    nothing is traced and ``land`` is the identity."""
+    if rate <= 0.0:
+        return lambda delta, step: delta
+    hit, s_step, row, col = stochastic_seu(rng_ref, salt, block_uid, n_steps,
+                                           bm, bn, rate)
+    return lambda delta, step: apply_seu(delta, row, col,
+                                         hit & (step == s_step), bit_shift)
 
 
 def apply_seu(delta, row, col, hit_now, bit_shift: int):
@@ -330,7 +371,7 @@ def render(spec: KernelSpec, *, k_steps: int, bm: int, bn: int, bk: int,
                 rowck_ref[...] = jnp.zeros_like(rowck_ref)
                 amax_ref[0, 0] = 0.0
                 bmax_ref[0, 0] = 0.0
-                rep_ref[...] = jnp.zeros_like(rep_ref)
+                init_report(rep_ref)
 
         # ---- mac: load (+ragged mask), MAC, checksums --------------------
         a = a_ref[0] if batched else a_ref[...]
@@ -354,8 +395,7 @@ def render(spec: KernelSpec, *, k_steps: int, bm: int, bn: int, bk: int,
             b = jnp.where(b_ok, b, jnp.zeros_like(b))
 
         if not ft:
-            acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32
-                                    ).astype(acc_dt)
+            acc_ref[...] += f32dot(a, b).astype(acc_dt)
 
             @pl.when(last)
             def _flush_plain():
@@ -378,7 +418,7 @@ def render(spec: KernelSpec, *, k_steps: int, bm: int, bn: int, bk: int,
         tau = jnp.maximum(rel_tau * F32EPS * k_elapsed
                           * amax_ref[0, 0] * bmax_ref[0, 0], 1e-30)
 
-        delta = jnp.dot(a, b, preferred_element_type=jnp.float32)
+        delta = f32dot(a, b)
 
         # ---- emulated SEU (scalar-prefetched spec) -----------------------
         # Uniform batched specs carry a 5-wide index [enable, batch, row,
@@ -432,8 +472,8 @@ def render(spec: KernelSpec, *, k_steps: int, bm: int, bn: int, bk: int,
         if mode == "inner":
             # Verify this step's contribution in isolation (thread-level
             # analogue: smallest protected unit, no cross-step state).
-            ck_col = jnp.dot(jnp.sum(af, axis=0, keepdims=True), bf)
-            ck_row = jnp.dot(af, jnp.sum(bf, axis=1, keepdims=True))
+            ck_col = f32dot(jnp.sum(af, axis=0, keepdims=True), bf)
+            ck_row = f32dot(af, jnp.sum(bf, axis=1, keepdims=True))
             d_col = jnp.sum(delta, axis=0, keepdims=True) - ck_col
             d_row = jnp.sum(delta, axis=1, keepdims=True) - ck_row
             delta, det, mag, row_l, col_l = _locate_correct_full(
@@ -444,14 +484,14 @@ def render(spec: KernelSpec, *, k_steps: int, bm: int, bn: int, bk: int,
         else:
             acc_ref[...] += delta
             if mode == "block":
-                colck_ref[...] += jnp.dot(jnp.sum(af, axis=0, keepdims=True),
-                                          bf)
+                colck_ref[...] += f32dot(jnp.sum(af, axis=0, keepdims=True),
+                                         bf)
             else:  # mode == "tile": one running column checksum per MXU band
                 for t in range(n_bands):
-                    colck_ref[t:t + 1, :] += jnp.dot(
+                    colck_ref[t:t + 1, :] += f32dot(
                         jnp.sum(af[t * MXU:(t + 1) * MXU], axis=0,
                                 keepdims=True), bf)
-            rowck_ref[...] += jnp.dot(af, jnp.sum(bf, axis=1, keepdims=True))
+            rowck_ref[...] += f32dot(af, jnp.sum(bf, axis=1, keepdims=True))
 
             def _verify_raw():
                 acc = acc_ref[...]
@@ -593,7 +633,7 @@ def render_tgmm(spec: KernelSpec, *, t_tiles: int, bm: int, bn: int, bk: int,
                 amax_ref[0, 0] = 0.0
                 bmax_ref[0, 0] = 0.0
                 t0_ref[0, 0] = t.astype(jnp.float32)
-                rep_ref[...] = jnp.zeros_like(rep_ref)
+                init_report(rep_ref)
 
         # ---- load + ragged masking (group edge, true K/N edges) ----------
         tn, tk = dims_ref[1], dims_ref[2]
@@ -607,8 +647,7 @@ def render_tgmm(spec: KernelSpec, *, t_tiles: int, bm: int, bn: int, bk: int,
         g = jnp.where(g_ok, g, 0.0)
 
         contract_rows = (((0,), (0,)), ((), ()))     # Xᵀ·G without transpose
-        delta = jax.lax.dot_general(x, g, contract_rows,
-                                    preferred_element_type=jnp.float32)
+        delta = f32dot(x, g, contract_rows)
 
         if not ft:
             acc_ref[...] += delta
@@ -656,8 +695,8 @@ def render_tgmm(spec: KernelSpec, *, t_tiles: int, bm: int, bn: int, bk: int,
         xsum = jnp.sum(x, axis=1, keepdims=True)             # (bm, 1): X e_K
         gsum = jnp.sum(g, axis=1, keepdims=True)             # (bm, 1): G e_N
         if mode == "inner":
-            ck_col = jax.lax.dot_general(xsum, g, contract_rows)   # (1, bn)
-            ck_row = jax.lax.dot_general(x, gsum, contract_rows)   # (bk, 1)
+            ck_col = f32dot(xsum, g, contract_rows)   # (1, bn)
+            ck_row = f32dot(x, gsum, contract_rows)   # (bk, 1)
             d_col = jnp.sum(delta, axis=0, keepdims=True) - ck_col
             d_row = jnp.sum(delta, axis=1, keepdims=True) - ck_row
             delta, det, mag, row_l, col_l = _locate_correct_full(
@@ -668,14 +707,13 @@ def render_tgmm(spec: KernelSpec, *, t_tiles: int, bm: int, bn: int, bk: int,
         else:
             acc_ref[...] += delta
             if mode == "block":
-                colck_ref[...] += jax.lax.dot_general(xsum, g, contract_rows)
+                colck_ref[...] += f32dot(xsum, g, contract_rows)
             else:  # "tile": one running column checksum per MXU band of dw
                 for b in range(n_bands):
                     xb = jnp.sum(x[:, b * MXU:(b + 1) * MXU], axis=1,
                                  keepdims=True)
-                    colck_ref[b:b + 1, :] += jax.lax.dot_general(
-                        xb, g, contract_rows)
-            rowck_ref[...] += jax.lax.dot_general(x, gsum, contract_rows)
+                    colck_ref[b:b + 1, :] += f32dot(xb, g, contract_rows)
+            rowck_ref[...] += f32dot(x, gsum, contract_rows)
 
             def _verify_raw():
                 acc = acc_ref[...]

@@ -18,12 +18,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.policy import FTConfig
-from ..pallas_compat import CompilerParams as _CompilerParams
 from ..autotune import MXU, KernelParams
 from . import emit
 from .spec import BatchedKernelSpec, KernelSpec
 
 REPORT_WIDTH = emit.REPORT_WIDTH
+
+
+def _report(lead, index_map):
+    """BlockSpec and shape of an FT report with leading dims ``lead``: one
+    REPORT_WIDTH row of fields per stationary output block, held in SMEM
+    (the fields are scalars). Mosaic takes a block whose trailing two dims
+    equal the array's, hence the unit dim before the row, which
+    `_squeeze_report` drops after the call."""
+    spec = pl.BlockSpec((1,) * (len(lead) + 1) + (REPORT_WIDTH,),
+                        lambda *idx: (*index_map(*idx), 0, 0),
+                        memory_space=pltpu.SMEM)
+    shape = jax.ShapeDtypeStruct((*lead, 1, REPORT_WIDTH), jnp.float32)
+    return spec, shape
+
+
+def _squeeze_report(rep):
+    return rep.reshape(rep.shape[:-2] + (REPORT_WIDTH,))
 
 
 def validate(spec: KernelSpec, params: KernelParams, m: int, n: int, k: int,
@@ -116,10 +132,9 @@ def kernel_call(a: jax.Array, b: jax.Array,
         if dims is None:
             dims = jnp.array([m, n, k], jnp.int32)
         prefetch = [inj_idx, inj_mag, rng, dims]
-        out_specs.append(pl.BlockSpec((1, 1, REPORT_WIDTH),
-                                      lambda i, j, s, *_: (i, j, 0)))
-        out_shape.append(jax.ShapeDtypeStruct(
-            (grid[0], grid[1], REPORT_WIDTH), jnp.float32))
+        rep_spec, rep_shape = _report(grid[:2], lambda i, j, s, *_: (i, j))
+        out_specs.append(rep_spec)
+        out_shape.append(rep_shape)
         scratch += [pltpu.VMEM((n_bands, bn), jnp.float32),
                     pltpu.VMEM((bm, 1), jnp.float32),
                     pltpu.SMEM((1, 1), jnp.float32),
@@ -129,7 +144,7 @@ def kernel_call(a: jax.Array, b: jax.Array,
         prefetch = [dims]
     assert len(prefetch) == lay.n_prefetch and len(operands) == lay.n_inputs
 
-    compiler_params = _CompilerParams(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                              pltpu.ARBITRARY))
 
@@ -160,7 +175,7 @@ def kernel_call(a: jax.Array, b: jax.Array,
     if not multi:
         return result, None
     result = list(result)
-    rep = result.pop() if spec.ft else None
+    rep = _squeeze_report(result.pop()) if spec.ft else None
     out = tuple(result) if spec.extra_outputs else result[0]
     return out, rep
 
@@ -201,10 +216,9 @@ def flash_fwd_call(q, k, v, inj_idx, inj_mag, rng, dims, *, bq: int,
             out_specs.append(pl.BlockSpec((1, bq, 1),
                                           lambda b, i, s, *_: (b, i, 0)))
             out_shape.append(jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32))
-    out_specs.append(pl.BlockSpec((1, 1, REPORT_WIDTH),
-                                  lambda b, i, s, *_: (b, i, 0)))
-    out_shape.append(jax.ShapeDtypeStruct((bh, sq // bq, REPORT_WIDTH),
-                                          jnp.float32))
+    rep_spec, rep_shape = _report(grid[:2], lambda b, i, s, *_: (b, i))
+    out_specs.append(rep_spec)
+    out_shape.append(rep_shape)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -227,13 +241,13 @@ def flash_fwd_call(q, k, v, inj_idx, inj_mag, rng, dims, *, bq: int,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY),
         ),
         interpret=interpret,
     )(inj_idx, inj_mag, rng, dims, q, k, v)
-    return tuple(result)
+    return (*result[:-1], _squeeze_report(result[-1]))
 
 
 def flash_decode_call(q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths,
@@ -254,6 +268,7 @@ def flash_decode_call(q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths,
     n_pages, _, page, _ = k_pages.shape
     max_pages = page_table.shape[1]
     grid = (g_rows, max_pages)
+    rep_spec, rep_shape = _report((g_rows, 1), lambda g, s, *_: (g, 0))
     kernel = functools.partial(
         flashft._flash_decode_kernel, kv_steps=grid[1], kvh=kvh, bq=bq,
         page=page, dh=dh, scale=scale, corrects=ft.corrects,
@@ -275,7 +290,7 @@ def flash_decode_call(q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dh), lambda g, s, *_: (g, 0, 0)),
-            pl.BlockSpec((1, 1, REPORT_WIDTH), lambda g, s, *_: (g, 0, 0)),
+            rep_spec,
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, dh), jnp.float32),
@@ -288,14 +303,14 @@ def flash_decode_call(q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((g_rows, bq, dh), q.dtype),
-            jax.ShapeDtypeStruct((g_rows, 1, REPORT_WIDTH), jnp.float32),
+            rep_shape,
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
         ),
         interpret=interpret,
     )(inj_idx, inj_mag, rng, lengths, page_table, q, k_pages, v_pages)
-    return out, rep
+    return out, _squeeze_report(rep)
 
 
 def flash_dq_call(q, k, v, g, m, l, di, inj_idx, inj_mag, rng, dims, *,
@@ -319,6 +334,7 @@ def flash_dq_call(q, k, v, g, m, l, di, inj_idx, inj_mag, rng, dims, *,
     kv_spec = pl.BlockSpec((1, bkv, dh),
                            lambda b, i, s, *_: (b // n_rep, s, 0))
     stat_spec = pl.BlockSpec((1, bq, 1), lambda b, i, s, *_: (b, i, 0))
+    rep_spec, rep_shape = _report(grid[:2], lambda b, i, s, *_: (b, i))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
@@ -326,8 +342,7 @@ def flash_dq_call(q, k, v, g, m, l, di, inj_idx, inj_mag, rng, dims, *,
                   stat_spec],
         out_specs=[
             pl.BlockSpec((1, bq, dh), lambda b, i, s, *_: (b, i, 0)),
-            pl.BlockSpec((1, 1, REPORT_WIDTH),
-                         lambda b, i, s, *_: (b, i, 0)),
+            rep_spec,
         ],
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
     )
@@ -336,15 +351,15 @@ def flash_dq_call(q, k, v, g, m, l, di, inj_idx, inj_mag, rng, dims, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq // bq, REPORT_WIDTH), jnp.float32),
+            rep_shape,
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY),
         ),
         interpret=interpret,
     )(inj_idx, inj_mag, rng, dims, q, k, v, g, m, l, di)
-    return dq, rep
+    return dq, _squeeze_report(rep)
 
 
 def flash_dkv_call(q, k, v, g, m, l, di, inj_idx, inj_mag, rng, dims, *,
@@ -374,15 +389,15 @@ def flash_dkv_call(q, k, v, g, m, l, di, inj_idx, inj_mag, rng, dims, *,
                            lambda b, kvi, r, qi, *_: (b, kvi, 0))
     out_spec = pl.BlockSpec((1, bkv, dh),
                             lambda b, kvi, r, qi, *_: (b, kvi, 0))
+    rep_spec, rep_shape = _report(grid[:2],
+                                  lambda b, kvi, r, qi, *_: (b, kvi))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=[q_spec, q_spec, stat_spec, stat_spec, stat_spec,
                   kv_spec, kv_spec],
         out_specs=[
-            out_spec, out_spec,
-            pl.BlockSpec((1, 1, REPORT_WIDTH),
-                         lambda b, kvi, r, qi, *_: (b, kvi, 0)),
+            out_spec, out_spec, rep_spec,
         ],
         scratch_shapes=[pltpu.VMEM((bkv, dh), jnp.float32),
                         pltpu.VMEM((bkv, dh), jnp.float32)],
@@ -393,16 +408,15 @@ def flash_dkv_call(q, k, v, g, m, l, di, inj_idx, inj_mag, rng, dims, *,
         out_shape=[
             jax.ShapeDtypeStruct((bkvh, skv, dh), k.dtype),
             jax.ShapeDtypeStruct((bkvh, skv, dh), v.dtype),
-            jax.ShapeDtypeStruct((bkvh, skv // bkv, REPORT_WIDTH),
-                                 jnp.float32),
+            rep_shape,
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY, pltpu.ARBITRARY),
         ),
         interpret=interpret,
     )(inj_idx, inj_mag, rng, dims, q, g, m, l, di, k, v)
-    return dk, dv, rep
+    return dk, dv, _squeeze_report(rep)
 
 
 @functools.partial(jax.jit,
@@ -482,11 +496,11 @@ def tgmm_kernel_call(x: jax.Array, g: jax.Array,
     out_shape = [jax.ShapeDtypeStruct((n_groups, k, n), out_dtype)]
     scratch = [pltpu.VMEM((bk, bn), jnp.dtype(spec.acc_dtype))]
     if spec.ft:
-        out_specs.append(pl.BlockSpec(
-            (1, 1, 1, REPORT_WIDTH),
-            lambda ki, ni, t, *pf: (pf[gpos][t], ki, ni, 0)))
-        out_shape.append(jax.ShapeDtypeStruct(
-            (n_groups, grid[0], grid[1], REPORT_WIDTH), jnp.float32))
+        rep_spec, rep_shape = _report(
+            (n_groups, grid[0], grid[1]),
+            lambda ki, ni, t, *pf: (pf[gpos][t], ki, ni))
+        out_specs.append(rep_spec)
+        out_shape.append(rep_shape)
         scratch += [pltpu.VMEM((n_bands, bn), jnp.float32),
                     pltpu.VMEM((bk, 1), jnp.float32),
                     pltpu.SMEM((1, 1), jnp.float32),
@@ -503,14 +517,14 @@ def tgmm_kernel_call(x: jax.Array, g: jax.Array,
     call = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=out_shape if spec.ft else out_shape[0],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret)
     result = call(*prefetch, x, g)
     if spec.ft:
         out, rep = result
-        return out, rep
+        return out, _squeeze_report(rep)
     return result, None
 
 
@@ -611,10 +625,7 @@ def batched_kernel_call(a: jax.Array, b: jax.Array,
         ]
         out_specs = [pl.BlockSpec((bm, bn), lambda i, j, s, *_: (i, j))]
         out_shape = [jax.ShapeDtypeStruct((t_buf, n), out_dtype)]
-        rep_spec = pl.BlockSpec((1, 1, REPORT_WIDTH),
-                                lambda i, j, s, *_: (i, j, 0))
-        rep_shape = jax.ShapeDtypeStruct(
-            (grid[0], grid[1], REPORT_WIDTH), jnp.float32)
+        rep_spec, rep_shape = _report(grid[:2], lambda i, j, s, *_: (i, j))
         semantics = (pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY)
     else:
         in_specs = [
@@ -626,10 +637,8 @@ def batched_kernel_call(a: jax.Array, b: jax.Array,
         out_specs = [pl.BlockSpec((1, bm, bn),
                                   lambda g, i, j, s, *_: (g, i, j))]
         out_shape = [jax.ShapeDtypeStruct((batch, m, n), out_dtype)]
-        rep_spec = pl.BlockSpec((1, 1, 1, REPORT_WIDTH),
-                                lambda g, i, j, s, *_: (g, i, j, 0))
-        rep_shape = jax.ShapeDtypeStruct(
-            (batch, grid[1], grid[2], REPORT_WIDTH), jnp.float32)
+        rep_spec, rep_shape = _report(grid[:3],
+                                      lambda g, i, j, s, *_: (g, i, j))
         semantics = (pltpu.PARALLEL, pltpu.PARALLEL, pltpu.PARALLEL,
                      pltpu.ARBITRARY)
 
@@ -643,7 +652,7 @@ def batched_kernel_call(a: jax.Array, b: jax.Array,
                     pltpu.SMEM((1, 1), jnp.float32)]
     assert len(prefetch) == lay.n_prefetch, (len(prefetch), lay)
 
-    compiler_params = _CompilerParams(dimension_semantics=semantics)
+    compiler_params = pltpu.CompilerParams(dimension_semantics=semantics)
     if prefetch:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
@@ -666,5 +675,5 @@ def batched_kernel_call(a: jax.Array, b: jax.Array,
 
     if spec.ft:
         out, rep = result
-        return out, rep
+        return out, _squeeze_report(rep)
     return result, None

@@ -8,9 +8,13 @@ small JSON file mapping
 
 so the (enumerate → score/measure) pass in `kernels.search` runs once per
 class per device and every later `autotune.best_params()` call is a dict
-lookup. The file lives at ``$REPRO_TUNE_CACHE`` when set, else
-``~/.cache/repro_tune.json`` (``$XDG_CACHE_HOME`` respected); a repo-local
-path can be passed explicitly (benchmarks, tests).
+lookup. Which file backs the process-wide cache (`default_cache`):
+
+  * ``$REPRO_TUNE_CACHE`` when set — a writable file (tests, tuning runs);
+  * otherwise the committed ``benchmarks/tuned/<device_kind>.json`` of the
+    checkout, read-only: a miss is scored by the roofline model and kept in
+    memory, never written. So the tiles a run compiles depend only on the
+    committed files and the code, not on a developer's home directory.
 
 Robustness: a missing, corrupt, or foreign-schema file degrades to an empty
 cache (never an exception on the hot path); writes are atomic
@@ -24,19 +28,23 @@ import os
 import tempfile
 from typing import Dict, Optional, Tuple
 
-from .autotune import KernelParams
+from .autotune import KernelParams, device_kind
 
 _SCHEMA = 1
 _ENV_VAR = "REPRO_TUNE_CACHE"
+
+
+#: Committed tuning files, one per device kind (`benchmarks/tune_campaign`).
+TUNED_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         os.pardir, os.pardir, os.pardir, "benchmarks",
+                         "tuned")
 
 
 def default_path() -> str:
     env = os.environ.get(_ENV_VAR)
     if env:
         return env
-    base = os.environ.get("XDG_CACHE_HOME",
-                          os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "repro_tune.json")
+    return os.path.normpath(os.path.join(TUNED_DIR, f"{device_kind()}.json"))
 
 
 def cache_key(device_kind: str, shape_class: str, in_bytes: int,
@@ -72,10 +80,12 @@ def cache_key(device_kind: str, shape_class: str, in_bytes: int,
 
 class TuneCache:
     """Dict-like view over the JSON tuning file. Entries are
-    ``key → [bm, bn, bk, shape_class]``."""
+    ``key → [bm, bn, bk, shape_class]``. A ``writable=False`` cache keeps
+    its `put`s in memory and never touches the file."""
 
-    def __init__(self, path: Optional[str] = None):
+    def __init__(self, path: Optional[str] = None, writable: bool = True):
         self.path = path or default_path()
+        self.writable = writable
         self._entries: Dict[str, Tuple[int, int, int, str]] = {}
         self._loaded = False
 
@@ -134,7 +144,7 @@ class TuneCache:
         self._ensure()
         self._entries[key] = (params.bm, params.bn, params.bk,
                               params.shape_class)
-        if persist:
+        if persist and self.writable:
             self.save()
 
     def __len__(self) -> int:
@@ -155,10 +165,11 @@ _DEFAULT: Optional[TuneCache] = None
 
 def default_cache() -> TuneCache:
     """Process-wide cache singleton (re-pointed by `reset`, e.g. after the
-    ``REPRO_TUNE_CACHE`` env var changes in tests)."""
+    ``REPRO_TUNE_CACHE`` env var changes in tests). Writable only when that
+    variable names the file."""
     global _DEFAULT
     if _DEFAULT is None or _DEFAULT.path != default_path():
-        _DEFAULT = TuneCache()
+        _DEFAULT = TuneCache(writable=bool(os.environ.get(_ENV_VAR)))
     return _DEFAULT
 
 

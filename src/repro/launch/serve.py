@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import registry
 from repro.configs.base import RunConfig
 from repro.core.policy import ONLINE_BLOCK, FT_OFF
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.train import serve as serve_lib
 
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--no-ft", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch.endswith("-smoke"):
         cfg = registry.get_smoke(args.arch[:-len("-smoke")])

@@ -3,9 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-7b-smoke \
         --steps 200 --batch 8 --seq 128 [--ckpt-dir /tmp/ckpt --resume]
 
-On a real TPU slice this same entry point runs under
-`jax.distributed.initialize()` with the production mesh; on CPU it runs the
-smoke-size configs (full configs are exercised via dryrun.py only).
+One process, one device, no mesh: it trains through `train_loop.train`
+on whatever device JAX finds first. The sharded step is exercised by
+`chip_smoke.py --chips 4` and tests/test_distributed.py.
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import jax
 from repro.configs import registry
 from repro.configs.base import RunConfig, ShapeConfig
 from repro.core.policy import ONLINE_BLOCK, FT_OFF
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import train_loop
 
 
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch.endswith("-smoke"):
         cfg = registry.get_smoke(args.arch[:-len("-smoke")])

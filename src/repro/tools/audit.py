@@ -29,6 +29,7 @@ import dataclasses
 from typing import Any, List, Tuple
 
 import jax
+import jax.extend
 import numpy as np
 
 
@@ -129,9 +130,7 @@ def pallas_call_names(fn, *args, **make_jaxpr_kwargs) -> List[str]:
             j = j.jaxpr
         for eqn in j.eqns:
             if eqn.primitive.name == "pallas_call":
-                info = eqn.params.get("name_and_src_info")
-                names.append(getattr(info, "name", None)
-                             or str(eqn.params.get("name", "")))
+                names.append(eqn.params["jaxpr"].debug_info.func_name)
             for sub in _sub_jaxprs(eqn.params):
                 walk(sub)
 

@@ -14,8 +14,8 @@ Note on units: with XLA SPMD, cost_analysis and the partitioned module are
 use per-device quantities directly against per-chip peak rates (numerically
 identical to the assignment's global-total formulation).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (3D-torus links assumed usable one axis at a time, conservative).
+Hardware constants come from `PEAKS`, one row per `device_kind`; the
+analytical models (tuner, planner, dry-run) price the chip `MODEL_KIND`.
 """
 from __future__ import annotations
 
@@ -23,9 +23,35 @@ import dataclasses
 import re
 from typing import Dict, Optional, Tuple
 
-PEAK_FLOPS = 197e12         # bf16 per chip
-HBM_BW = 819e9              # bytes/s per chip
-LINK_BW = 50e9              # bytes/s per ICI link
+#: Published per-chip peaks, keyed by `jax.Device.device_kind`. Source:
+#: Google Cloud documentation, "TPU v5e" (system architecture table):
+#: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+#: of chip-to-chip interconnect over 4 ICI ports.
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9, "ici_bw": 200e9,
+                    "ici_links": 4},
+}
+
+#: The chip the analytical models assume.
+MODEL_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict:
+    """The `PEAKS` row of ``device_kind``; an unknown kind is an error, never
+    a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add a row to roofline.PEAKS "
+                         f"with its source") from None
+
+
+PEAK_FLOPS = PEAKS[MODEL_KIND]["bf16_flops"]            # per chip
+HBM_BW = PEAKS[MODEL_KIND]["hbm_bw"]                    # bytes/s per chip
+LINK_BW = (PEAKS[MODEL_KIND]["ici_bw"]
+           / PEAKS[MODEL_KIND]["ici_links"])            # bytes/s per link
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -118,12 +144,9 @@ class Roofline:
 
 
 def cost_dict(compiled) -> Dict:
-    """`compiled.cost_analysis()` normalized across jax versions: some
-    return a flat dict, others a one-element list of dicts."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """`compiled.cost_analysis()` as a plain dict (empty when the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def kernel_time_s(flops: float, hbm_bytes: float,

@@ -6,8 +6,8 @@ import pytest
 
 @pytest.fixture(scope="session", autouse=True)
 def _isolated_tune_cache(tmp_path_factory):
-    """Point the autotuner's persistent cache at a per-session temp file so
-    tests neither read a developer's warm ~/.cache nor leave one behind."""
+    """Point the autotuner's cache at a writable per-session temp file, so
+    tests that write tuning entries never touch the committed files."""
     path = str(tmp_path_factory.mktemp("tune") / "repro_tune.json")
     prev = os.environ.get("REPRO_TUNE_CACHE")
     os.environ["REPRO_TUNE_CACHE"] = path

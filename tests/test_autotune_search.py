@@ -151,3 +151,30 @@ def test_best_params_divides_padded_problem(fresh_cache):
         mp, np_, kp = autotune.padded_shape(m, n, k, p)
         assert mp % p.bm == 0 and np_ % p.bn == 0 and kp % p.bk == 0
         assert p.vmem_bytes() <= VMEM_BUDGET
+
+
+def test_default_cache_is_the_committed_file_read_only(monkeypatch):
+    """Without REPRO_TUNE_CACHE the tiles come from the committed
+    benchmarks/tuned/<device_kind>.json (never rewritten) plus the roofline
+    model, and the request path never times a candidate."""
+    monkeypatch.delenv("REPRO_TUNE_CACHE", raising=False)
+    tune_cache.reset()
+    try:
+        cache = tune_cache.default_cache()
+        assert not cache.writable
+        assert cache.path == os.path.normpath(os.path.join(
+            tune_cache.TUNED_DIR, f"{autotune.device_kind()}.json"))
+        before = open(cache.path).read() if os.path.exists(cache.path) \
+            else None
+
+        def no_timing(*a, **k):
+            raise AssertionError("request path measured a candidate")
+        monkeypatch.setattr(search, "measure_candidates", no_timing)
+        p = autotune.best_params(333, 777, 1111)
+        assert autotune.best_params(333, 777, 1111) == p
+        assert len(cache) > 0
+        after = open(cache.path).read() if os.path.exists(cache.path) \
+            else None
+        assert after == before
+    finally:
+        tune_cache.reset()

@@ -79,6 +79,27 @@ def test_batched_dot_clean_and_injected():
                                rtol=1e-4, atol=1e-2)
 
 
+@pytest.mark.parametrize("call", ["dot", "batched", "nonfused"])
+def test_jnp_abft_materializes_its_operands(call):
+    """The GEMM and its checksums must read one materialized copy of each
+    operand. Fused into both and recomputed, a producer (here tanh) need
+    not come out bit for bit the same, and the checksums then encode
+    another matrix: false detections on the TPU, invisible on the CPU."""
+    from repro.tools import audit
+    a, w = _ab()
+    fns = {
+        "dot": lambda a, w: ft_dot(jnp.tanh(a), w, ft=ONLINE_BLOCK),
+        "batched": lambda a, w: ft_batched_dot(jnp.tanh(a)[None], w[None],
+                                               ft=ONLINE_BLOCK),
+        "nonfused": lambda a, w: ft_dot(jnp.tanh(a), w,
+                                        ft=NONFUSED_BASELINE),
+    }
+    n = audit.count_primitives(fns[call], a, w,
+                               primitive="optimization_barrier")
+    # nonfused pins its three passes with barriers of its own
+    assert n == (4 if call == "nonfused" else 1)
+
+
 def test_telemetry_scope_counts():
     a, w = _ab()
     with ft_scope() as s:

@@ -40,6 +40,7 @@ def test_pjit_train_step_shards_and_matches_single_device():
         from repro.configs.base import RunConfig
         from repro.core.policy import ONLINE_BLOCK
         from repro.distributed import sharding as shd
+        from repro.launch.mesh import make_mesh
         from repro.models import model_zoo
         from repro.optim import adamw
         from repro.train import train_loop
@@ -63,7 +64,7 @@ def test_pjit_train_step_shards_and_matches_single_device():
         _, _, m_ref = jax.jit(step)(params, opt, batch, jnp.asarray(0), None)
         ref = float(m_ref["loss"])
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with shd.use_mesh(mesh):
             specs = shd.param_specs(params)
             p_sh = jax.device_put(params, jax.tree.map(
@@ -94,9 +95,10 @@ def test_ft_adds_no_collectives():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import ft_dot
         from repro.core.policy import ONLINE_BLOCK, FT_OFF
+        from repro.launch.mesh import make_mesh
         from repro.tools import roofline
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         x = jax.ShapeDtypeStruct((256, 512), jnp.float32,
                                  sharding=NamedSharding(mesh, P("data")))
         w = jax.ShapeDtypeStruct((512, 384), jnp.float32,
@@ -127,9 +129,10 @@ def test_checkpoint_elastic_reshard_8_to_4():
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.ckpt import Checkpointer
+        from repro.launch.mesh import make_mesh
 
         tree = {"w": jnp.arange(64.0).reshape(8, 8)}
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = make_mesh((8,), ("data",))
         sh8 = {"w": NamedSharding(mesh8, P("data"))}
         tree8 = jax.device_put(tree, sh8)
         d = tempfile.mkdtemp()

@@ -32,6 +32,7 @@ def test_small_mesh_train_lowering_compiles_with_shardings():
         from repro.configs.base import RunConfig, ShapeConfig
         from repro.core.policy import ONLINE_BLOCK
         from repro.distributed import sharding as shd
+        from repro.launch.mesh import make_mesh
         from repro.models import model_zoo
         from repro.optim import adamw
         from repro.tools import roofline
@@ -41,7 +42,7 @@ def test_small_mesh_train_lowering_compiles_with_shardings():
                                   n_heads=8, n_kv_heads=4)
         shape = ShapeConfig("t", 64, 8, "train")
         run = RunConfig(model=cfg, ft=ONLINE_BLOCK, attn_chunk=32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with shd.use_mesh(mesh):
             mod = model_zoo.module_for(cfg)
             p_struct = jax.eval_shape(
@@ -84,13 +85,14 @@ def test_small_mesh_decode_lowering_compiles():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import registry
         from repro.distributed import sharding as shd
+        from repro.launch.mesh import make_mesh
         from repro.models import model_zoo
         from repro.models.blocks import Ctx
         from repro.core.policy import ONLINE_BLOCK
 
         cfg = registry.get_smoke("zamba2-2.7b")
         mod = model_zoo.module_for(cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with shd.use_mesh(mesh, {"seq": None}):
             p_struct = jax.eval_shape(
                 lambda: mod.init(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
@@ -134,3 +136,13 @@ def test_roofline_terms_and_bottleneck():
     assert abs(rl.memory_s - 2.0) < 1e-6
     assert rl.bottleneck == "memory"
     assert abs(rl.useful_ratio - 100e12 / 197e12) < 1e-6
+
+
+def test_peaks_table_keyed_by_device_kind():
+    import pytest
+    from repro.tools import roofline
+    row = roofline.peaks("TPU v5 lite")
+    assert row["bf16_flops"] == roofline.PEAK_FLOPS == 197e12
+    assert row["hbm_bw"] == roofline.HBM_BW == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("cpu")
