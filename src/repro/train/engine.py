@@ -25,6 +25,24 @@ the same JSONL stream — and the same storm detector — as training. The
 engine additionally records serving-shape metrics per step: live slots,
 free pages, decoded tokens, and a TTFT histogram at admission.
 
+Host spans (`tools.trace.span`, in the process-wide ring and, under a
+profiler trace, on its host track) mark each layer boundary:
+
+  serve/step       all of `step()`
+    serve/admit      one admission (``rid``), after its ``serve/queued``
+                     record (submit → admission start)
+      serve/prefill    the batch-1 prefill call (``rid``, ``prompt_len``)
+      serve/kv_write   the prompt KV scatter into pages (``rid``)
+      serve/sample     the first token's host sync
+    serve/prepare    page reservation and the page-table/length/token uploads
+    serve/dispatch   the decode call (``live``, ``n_slots``, ``free_pages``)
+    serve/sample     the host sync of the sampled tokens
+    serve/retire     the bookkeeping after sampling (``finished``)
+
+A compile or retrace inside any of them shows as a ``jax/compile`` or
+``jax/trace`` record under it. The sink's ``live_slots``/``free_pages``
+gauges take the values the ``serve/dispatch`` span carries.
+
 Length bookkeeping: `PageAllocator.ensure(slot, n)` reserves *capacity*;
 the device-visible `cache["length"]` is the engine's decoded-so-far count
 (`cur_len`) — ensure runs for `cur_len + 1` BEFORE each step so the page
@@ -45,6 +63,7 @@ from repro.configs.base import ModelConfig, RunConfig
 from repro.core import telemetry
 from repro.models import transformer as tfm
 from repro.models.blocks import Ctx
+from repro.tools import trace
 from . import kv_cache
 
 
@@ -150,6 +169,7 @@ class ServeEngine:
 
         self._prefill = jax.jit(prefill_fn)
         self._decode = jax.jit(decode_fn, donate_argnums=(2,))
+        trace.watch_compiles()
 
     # -- request intake ----------------------------------------------------
 
@@ -173,20 +193,28 @@ class ServeEngine:
     # -- internals ---------------------------------------------------------
 
     def _sample(self, logits: jax.Array) -> np.ndarray:
-        if self.ec.temperature <= 0.0:
-            return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        self._draws += 1
-        k = jax.random.fold_in(self._key, self._draws)
-        return np.asarray(
-            jax.random.categorical(k, logits / self.ec.temperature),
-            np.int32)
+        with trace.span("serve/sample"):
+            if self.ec.temperature <= 0.0:
+                return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            self._draws += 1
+            k = jax.random.fold_in(self._key, self._draws)
+            return np.asarray(
+                jax.random.categorical(k, logits / self.ec.temperature),
+                np.int32)
 
-    def _emit(self, rep, phase: str, n_tokens: int) -> None:
+    def _gauges(self) -> Dict[str, int]:
+        """Slot and page occupancy now: what ``serve/dispatch`` carries and
+        the sink's gauges report."""
+        return {"live": sum(r is not None for r in self.slot_req),
+                "n_slots": self.ec.n_slots, "free_pages": self.alloc.n_free}
+
+    def _emit(self, rep, phase: str, n_tokens: int,
+              gauges: Dict[str, int]) -> None:
         sink = self.sink
         sink.record_ft(rep, step=self._serve_step)
         sink.gauge("phase", phase)
-        sink.gauge("live_slots", sum(r is not None for r in self.slot_req))
-        sink.gauge("free_pages", self.alloc.n_free)
+        sink.gauge("live_slots", gauges["live"])
+        sink.gauge("free_pages", gauges["free_pages"])
         sink.count("decoded_tokens" if phase == "decode" else "prefill_tokens",
                    n_tokens)
         sink.step_end(self._serve_step)
@@ -210,31 +238,41 @@ class ServeEngine:
         KV into freshly allocated pages, and samples the first token."""
         while self.queue and self.alloc.can_admit(len(self.queue[0].prompt)):
             req = self.queue.popleft()
-            L = len(req.prompt)
-            slot, _ = self.alloc.alloc_slot(L)
-            dcache = tfm.init_cache(self.cfg, 1, L, self.dtype)
-            toks = jnp.asarray(req.prompt[None], jnp.int32)
+            t_ns = time.perf_counter_ns()
+            waited = self._clock() - req.t_submit
+            trace.record("serve/queued", t_ns - int(waited * 1e9), t_ns,
+                         rid=req.rid)
+            with trace.span("serve/admit", rid=req.rid):
+                self._admit_one(req)
+
+    def _admit_one(self, req: Request) -> None:
+        L = len(req.prompt)
+        slot, _ = self.alloc.alloc_slot(L)
+        dcache = tfm.init_cache(self.cfg, 1, L, self.dtype)
+        toks = jnp.asarray(req.prompt[None], jnp.int32)
+        with trace.span("serve/prefill", prompt_len=L):
             if self.sink is not None:
                 logits, dcache, rep = self._prefill(self.params, toks, dcache)
             else:
                 logits, dcache = self._prefill(self.params, toks, dcache)
+        with trace.span("serve/kv_write"):
             self.cache = kv_cache.write_prefill(
                 self.cache, slot, jnp.asarray(self.alloc.page_table[slot]),
                 dcache["k"][:, 0], dcache["v"][:, 0], L)
-            tok = int(self._sample(logits.reshape(1, -1))[0])
-            now = self._clock()
-            self.slot_req[slot] = req
-            self.cur_len[slot] = L
-            self.next_tok[slot] = tok
-            self.n_new[slot] = 1
-            self.gen[slot] = [tok]
-            self.ttft[slot] = now - req.t_submit
-            if self.sink is not None:
-                self.sink.count("requests", 1)
-                self.sink.histogram("ttft_s", self.ttft[slot])
-                self._emit(rep, "prefill", L)
-            if self._done(slot, tok):
-                self._finish(slot)
+        tok = int(self._sample(logits.reshape(1, -1))[0])
+        now = self._clock()
+        self.slot_req[slot] = req
+        self.cur_len[slot] = L
+        self.next_tok[slot] = tok
+        self.n_new[slot] = 1
+        self.gen[slot] = [tok]
+        self.ttft[slot] = now - req.t_submit
+        if self.sink is not None:
+            self.sink.count("requests", 1)
+            self.sink.histogram("ttft_s", self.ttft[slot])
+            self._emit(rep, "prefill", L, self._gauges())
+        if self._done(slot, tok):
+            self._finish(slot)
 
     def _done(self, slot: int, tok: int) -> bool:
         req = self.slot_req[slot]
@@ -247,43 +285,53 @@ class ServeEngine:
         """Admit what fits, then run ONE decode step over every live slot.
         Returns False when the engine is fully drained (no live slots and
         an empty queue) — i.e. `while eng.step(): pass` serves everything."""
-        self._admit()
-        live = [s for s in range(self.ec.n_slots)
-                if self.slot_req[s] is not None]
-        if not live:
-            if self.queue:
-                # Idle engine (every page free) yet the head request still
-                # does not fit: it never will — fail loudly instead of
-                # spinning. Reachable only with a pool sized below one
-                # worst-case request (slack ≪ 1 or tiny max_pages).
-                raise RuntimeError(
-                    f"request rid={self.queue[0].rid} (prompt_len="
-                    f"{len(self.queue[0].prompt)}) cannot be admitted even "
-                    f"by an idle engine: page pool too small "
-                    f"({self.alloc.n_free} free pages)")
-            return False
-        for s in live:
-            self.alloc.ensure(s, int(self.cur_len[s]) + 1)
-        self.cache["page_table"] = jnp.asarray(self.alloc.page_table)
-        self.cache["length"] = jnp.asarray(self.cur_len)
-        tok = jnp.asarray(self.next_tok[:, None], jnp.int32)
-        if self.sink is not None:
-            logits, self.cache, rep = self._decode(self.params, tok,
-                                                   self.cache)
-        else:
-            logits, self.cache = self._decode(self.params, tok, self.cache)
-        nxt = self._sample(logits.reshape(self.ec.n_slots, -1))
-        if self.sink is not None:
-            self._emit(rep, "decode", len(live))
-        for s in live:
-            self.cur_len[s] += 1
-            t = int(nxt[s])
-            self.next_tok[s] = t
-            self.gen[s].append(t)
-            self.n_new[s] += 1
-            if self._done(s, t):
-                self._finish(s)
-        return True
+        with trace.span("serve/step"):
+            self._admit()
+            live = [s for s in range(self.ec.n_slots)
+                    if self.slot_req[s] is not None]
+            if not live:
+                if self.queue:
+                    # Idle engine (every page free) yet the head request
+                    # still does not fit: it never will — fail loudly
+                    # instead of spinning. Reachable only with a pool sized
+                    # below one worst-case request (slack ≪ 1 or tiny
+                    # max_pages).
+                    raise RuntimeError(
+                        f"request rid={self.queue[0].rid} (prompt_len="
+                        f"{len(self.queue[0].prompt)}) cannot be admitted "
+                        f"even by an idle engine: page pool too small "
+                        f"({self.alloc.n_free} free pages)")
+                return False
+            with trace.span("serve/prepare"):
+                for s in live:
+                    self.alloc.ensure(s, int(self.cur_len[s]) + 1)
+                self.cache["page_table"] = jnp.asarray(self.alloc.page_table)
+                self.cache["length"] = jnp.asarray(self.cur_len)
+                tok = jnp.asarray(self.next_tok[:, None], jnp.int32)
+            gauges = self._gauges()
+            with trace.span("serve/dispatch", **gauges):
+                if self.sink is not None:
+                    logits, self.cache, rep = self._decode(self.params, tok,
+                                                           self.cache)
+                else:
+                    logits, self.cache = self._decode(self.params, tok,
+                                                      self.cache)
+            nxt = self._sample(logits.reshape(self.ec.n_slots, -1))
+            if self.sink is not None:
+                self._emit(rep, "decode", len(live), gauges)
+            with trace.span("serve/retire") as retire:
+                finished = 0
+                for s in live:
+                    self.cur_len[s] += 1
+                    t = int(nxt[s])
+                    self.next_tok[s] = t
+                    self.gen[s].append(t)
+                    self.n_new[s] += 1
+                    if self._done(s, t):
+                        self._finish(s)
+                        finished += 1
+                retire.attrs["finished"] = finished
+            return True
 
     def run(self) -> List[Result]:
         """Drain the queue; returns results sorted by request id."""

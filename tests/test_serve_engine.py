@@ -361,3 +361,57 @@ def test_engine_unsupported_family_raises(tiny_params):
     run = RunConfig(model=cfg, ft=FT_PALLAS, dtype="float32")
     with pytest.raises(NotImplementedError):
         ServeEngine(tiny_params, cfg, run, EngineConfig())
+
+
+# ---------------------------------------------------------------------------
+# engine: host spans at each layer boundary (tools/trace's ring)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("warm", [False, True])
+def test_engine_spans_one_admission_and_one_decode_step(tiny_params, warm):
+    """One admission and one decode step leave exactly the records of the
+    engine's span table, each under its parent with its attributes; a
+    prompt length no earlier step compiled shows as a ``jax/compile``
+    record under ``serve/prefill``, a warmed one as none."""
+    import time
+    from repro.tools import trace
+    run = RunConfig(model=TINY, ft=FT_PALLAS, dtype="float32")
+    eng = ServeEngine(tiny_params, TINY, run,
+                      EngineConfig(max_len=64, n_slots=2, page_size=8))
+    prompt = np.arange(1, 12)
+    if warm:
+        eng.submit(prompt, max_new_tokens=2)
+        eng.run()
+    rid = eng.submit(prompt, max_new_tokens=3)
+    t0 = time.perf_counter_ns()
+    assert eng.step()
+    recs = [r for r in trace.records() if r.end_ns >= t0]
+    by_id = {r.id: r for r in recs}
+
+    def parent(r):
+        return by_id[r.parent].name if r.parent in by_id else None
+
+    got = sorted((r.name, parent(r), r.rid, tuple(sorted(r.attrs.items())))
+                 for r in recs if r.name.startswith("serve/"))
+    free = eng.alloc.n_free
+    want = sorted([
+        ("serve/step", None, None, ()),
+        ("serve/queued", "serve/step", rid, ()),
+        ("serve/admit", "serve/step", rid, ()),
+        ("serve/prefill", "serve/admit", rid, (("prompt_len", 11),)),
+        ("serve/kv_write", "serve/admit", rid, ()),
+        ("serve/sample", "serve/admit", rid, ()),
+        ("serve/prepare", "serve/step", None, ()),
+        ("serve/dispatch", "serve/step", None,
+         (("free_pages", free), ("live", 1), ("n_slots", 2))),
+        ("serve/sample", "serve/step", None, ()),
+        ("serve/retire", "serve/step", None, (("finished", 0),)),
+    ])
+    assert got == want
+    queued = next(r for r in recs if r.name == "serve/queued")
+    admit = next(r for r in recs if r.name == "serve/admit")
+    assert queued.start_ns <= t0 and queued.end_ns <= admit.start_ns
+    compiles = [r for r in recs if r.name == "jax/compile"
+                and parent(r) == "serve/prefill"]
+    assert bool(compiles) == (not warm), compiles
+    assert all(r.rid == rid for r in compiles)
