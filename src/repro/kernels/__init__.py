@@ -162,13 +162,14 @@ engine's `transformer.paged_decode_step` launches per layer)::
     from repro.kernels import ops
     from repro.train import kv_cache as kvc
 
-    # KV lives in a page pool (n_pages, KVH, page, dh) — ONE page is ONE
-    # kv block of the kernel, streamed through a scalar-prefetched page
-    # table; lengths int32[B] are per-row ragged (a slot at 17 tokens and
+    # KV lives in a stacked page pool (n_layers, n_pages, KVH, page, dh)
+    # — ONE page is ONE kv block of the kernel, streamed through a
+    # scalar-prefetched page table and layer index, so the pool is read in
+    # place; lengths int32[B] are per-row ragged (a slot at 17 tokens and
     # a slot at 4096 share the launch, each masked at ITS length; dead
     # slots ride the reserved null page and write exact zeros).
     out, rep = ops.flash_ft_decode(q, k_pages, v_pages, lengths,
-                                   page_table, ft=ft)
+                                   page_table, layer, ft=ft)
     # q (B, H, dh) with GQA folded to grid rows g = slot * KVH + kv_head
     # (n_rep query heads per row — KV never repeat-materialized); rep
     # (B*KVH, 1, 8) carries [det, corr, row, col, mag, max_res, tau, k].

@@ -269,7 +269,7 @@ def _flash_ft_kernel(inj_ref, mag_ref, rng_ref, dims_ref,
 # ---------------------------------------------------------------------------
 
 def _flash_decode_kernel(inj_ref, mag_ref, rng_ref, len_ref, tbl_ref,
-                         q_ref, k_ref, v_ref,
+                         layer_ref, q_ref, k_ref, v_ref,
                          o_ref, rep_ref, acc_ref, m_ref, l_ref, *,
                          kv_steps: int, kvh: int, bq: int, page: int,
                          dh: int, scale: float, corrects: bool,
@@ -283,8 +283,9 @@ def _flash_decode_kernel(inj_ref, mag_ref, rng_ref, len_ref, tbl_ref,
     the ops wrapper) at ONE decode position, and the reduction walk streams
     the slot's KV-cache pages. The page table (``tbl_ref``) is consumed by
     the K/V *index maps* — each kv step DMAs exactly the physical page the
-    slot's table names, so thousands of slots share one pool with no dense
-    padding; the body itself reads only the per-slot true length
+    slot's table names, out of the layer ``layer_ref`` names of the stacked
+    pool, so thousands of slots share one pool with no dense padding and no
+    per-layer copy; the body itself reads only the per-slot true length
     (``len_ref``, the ragged `int32[B]` replacing the forward's one
     (Sq, Skv) pair). Both GEMMs carry the same fused ABFT as the forward:
     S = QKᵀ verified before masking, Δ = PV verified with the τ clamped to
@@ -292,7 +293,7 @@ def _flash_decode_kernel(inj_ref, mag_ref, rng_ref, len_ref, tbl_ref,
     stays exact on ragged rows. Slots with true length 0 (dead slots
     streaming the null page) never execute a step and flush exact zeros via
     the m-degenerate clamp."""
-    del tbl_ref                      # routing only — consumed by index maps
+    del tbl_ref, layer_ref           # routing only — consumed by index maps
     g = pl.program_id(0)
     s = pl.program_id(1)
     slot = g // kvh
@@ -724,7 +725,7 @@ def flash_ft_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def flash_ft_decode_attention(q: jax.Array, k_pages: jax.Array,
                               v_pages: jax.Array, inj_idx: jax.Array,
                               inj_mag: jax.Array, lengths: jax.Array,
-                              page_table: jax.Array,
+                              page_table: jax.Array, layer: jax.Array,
                               rng: Optional[jax.Array] = None, *,
                               kvh: int, ft: FTConfig,
                               interpret: bool = False,
@@ -732,17 +733,19 @@ def flash_ft_decode_attention(q: jax.Array, k_pages: jax.Array,
                               scale: float = None):
     """Paged ragged decode: q (B·kvh, bq, dh) — one stationary block per
     (slot, kv head) holding the head's n_rep GQA query rows at the slot's
-    current position; k_pages/v_pages (n_pages, kvh, page, dh) — ONE
-    layer's shared page pool; lengths int32[B] per-slot true kv lengths
-    (the ragged vector; 0 = dead slot → exact-zero output); page_table
-    int32[B, max_pages] physical page ids (NULL-padded), scalar-prefetched
-    into the K/V index maps. inj_idx int32[6] = [enable, g, 0, kv_step,
+    current position; k_pages/v_pages (n_layers, n_pages, kvh, page, dh)
+    — the stacked shared page pool, read at layer ``layer`` (int32[1]);
+    lengths int32[B] per-slot true kv lengths (the ragged vector; 0 = dead
+    slot → exact-zero output); page_table int32[B, max_pages] physical
+    page ids (NULL-padded); table and layer are scalar-prefetched into the
+    K/V index maps. inj_idx int32[6] = [enable, g, 0, kv_step,
     row, col] with g = slot·kvh + head (`encode_injection(spec, bh=g)`);
     rng int32[3] the stochastic hook (`encode_rng`). Returns
     (out (B·kvh, bq, dh), report (B·kvh, 1, W))."""
     g_rows, bq, dh = q.shape
-    n_pages, kvh_p, page, dh_k = k_pages.shape
+    _, n_pages, kvh_p, page, dh_k = k_pages.shape
     assert kvh_p == kvh and dh_k == dh, (k_pages.shape, kvh, dh)
+    assert layer.shape == (1,), layer.shape
     assert g_rows == page_table.shape[0] * kvh, (q.shape, page_table.shape,
                                                  kvh)
     assert lengths.shape == (page_table.shape[0],), (lengths.shape,
@@ -752,7 +755,7 @@ def flash_ft_decode_attention(q: jax.Array, k_pages: jax.Array,
     scale = scale if scale is not None else dh ** -0.5
     return tregistry.flash_decode_call(
         q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths, page_table,
-        kvh=kvh, ft=ft, interpret=interpret, protect_qk=protect_qk,
+        layer, kvh=kvh, ft=ft, interpret=interpret, protect_qk=protect_qk,
         scale=scale)
 
 

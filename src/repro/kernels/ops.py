@@ -444,7 +444,7 @@ def flash_ft(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 @traced("kernel/flash_decode")
 def flash_ft_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    lengths: jax.Array, page_table: jax.Array, *,
+                    lengths: jax.Array, page_table: jax.Array, layer, *,
                     ft: FTConfig = ONLINE_BLOCK,
                     spec: Optional[InjectionSpec] = None, inj_g: int = 0,
                     interpret: Optional[bool] = None,
@@ -454,13 +454,15 @@ def flash_ft_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     (PR 9) — the serving engine's attention kernel.
 
     q: (B, H, dh) — one query position per serving slot; k_pages/v_pages:
-    (n_pages, KVH, page, dh) — ONE layer of the shared page pool
-    (`train.kv_cache`); lengths: int32[B] per-slot TRUE kv lengths (the
-    ragged vector that replaces the forward's one (Sq, Skv) pair; 0 marks
-    a dead slot, which returns exact zeros); page_table: int32[B,
-    max_pages] physical page ids, scalar-prefetched into the kernel's K/V
-    index maps so each (slot, head) grid row streams exactly its own
-    pages out of the pool.
+    (n_layers, n_pages, KVH, page, dh) — the whole stacked page pool
+    (`train.kv_cache`), read where it lies at ``layer`` (an int or an int32
+    scalar such as a layer scan's index; a caller holding one layer's pool
+    passes ``pool[None]`` and layer 0); lengths: int32[B] per-slot TRUE kv
+    lengths (the ragged vector that replaces the forward's one (Sq, Skv)
+    pair; 0 marks a dead slot, which returns exact zeros); page_table:
+    int32[B, max_pages] physical page ids. Table and layer are
+    scalar-prefetched into the kernel's K/V index maps so each (slot, head)
+    grid row streams exactly its own pages out of the pool.
 
     dh must be lane-aligned (128-multiple) — the paged pool is laid out at
     kernel geometry, so there is no pad-and-slice here; callers with
@@ -475,7 +477,7 @@ def flash_ft_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     (out (B, H, dh), report (B·KVH, 1, W))."""
     from . import flashft
     b, h, dh = q.shape
-    n_pages, kvh, page, dh_k = k_pages.shape
+    _, n_pages, kvh, page, dh_k = k_pages.shape
     assert v_pages.shape == k_pages.shape, (k_pages.shape, v_pages.shape)
     assert dh == dh_k, (q.shape, k_pages.shape)
     assert h % kvh == 0, (h, kvh)
@@ -514,9 +516,10 @@ def flash_ft_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         qg = jnp.pad(qg, ((0, 0), (0, bq - n_rep), (0, 0)))
     out, rep = flashft.flash_ft_decode_attention(
         qg, k_pages, v_pages, inj_idx, inj_mag,
-        lengths.astype(jnp.int32), page_table.astype(jnp.int32), rng,
-        kvh=kvh, ft=ft, interpret=_should_interpret(interpret),
-        protect_qk=protect_qk, scale=dh ** -0.5)
+        lengths.astype(jnp.int32), page_table.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), rng, kvh=kvh, ft=ft,
+        interpret=_should_interpret(interpret), protect_qk=protect_qk,
+        scale=dh ** -0.5)
     return out[:, :n_rep].reshape(b, h, dh), rep
 
 

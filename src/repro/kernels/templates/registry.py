@@ -251,21 +251,23 @@ def flash_fwd_call(q, k, v, inj_idx, inj_mag, rng, dims, *, bq: int,
 
 
 def flash_decode_call(q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths,
-                      page_table, *, kvh: int, ft: FTConfig,
+                      page_table, layer, *, kvh: int, ft: FTConfig,
                       interpret: bool, protect_qk: bool, scale: float):
     """Paged ragged decode launch (PR 9). Grid (B·kvh, max_pages): one row
     per (slot, kv head), reduction walk over the slot's KV pages. The
-    scalar-prefetched page table drives the K/V *index maps* — kv step s of
-    grid row g DMAs physical page ``page_table[g // kvh, s]`` of kv head
-    ``g % kvh`` straight out of the shared (n_pages, kvh, page, dh) pool,
-    so the kernel streams exactly the slot's pages (NULL entries stream the
-    trash page; the in-body length mask keeps them unattended). The length
-    vector replaces the forward's (Sq, Skv) dims pair — per-row ragged
-    dispatch. Returns (out (B·kvh, bq, dh), report (B·kvh, 1, W))."""
+    scalar-prefetched page table and layer index (int32[1]) drive the K/V
+    *index maps* — kv step s of grid row g DMAs physical page
+    ``page_table[g // kvh, s]`` of kv head ``g % kvh`` of layer ``layer``
+    straight out of the shared (n_layers, n_pages, kvh, page, dh) pool, so
+    the kernel streams exactly the slot's pages and the pool is read where
+    it lies (no per-layer slice of it is ever materialized; NULL entries
+    stream the trash page; the in-body length mask keeps them unattended).
+    The length vector replaces the forward's (Sq, Skv) dims pair — per-row
+    ragged dispatch. Returns (out (B·kvh, bq, dh), report (B·kvh, 1, W))."""
     from .. import flashft
 
     g_rows, bq, dh = q.shape
-    n_pages, _, page, _ = k_pages.shape
+    _, n_pages, _, page, _ = k_pages.shape
     max_pages = page_table.shape[1]
     grid = (g_rows, max_pages)
     rep_spec, rep_shape = _report((g_rows, 1), lambda g, s, *_: (g, 0))
@@ -275,13 +277,14 @@ def flash_decode_call(q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths,
         rel_tau=ft.rel_tau, protect_qk=protect_qk,
         inject_rate=ft.inject_rate, bit_shift=ft.inject_bit_shift)
 
-    # prefetch order: inj_idx, inj_mag, rng, lengths, page_table — the
-    # table is pf[4] inside the index maps.
+    # prefetch order: inj_idx, inj_mag, rng, lengths, page_table, layer —
+    # the table is pf[4] and the layer pf[5] inside the index maps. The
+    # layer axis is squeezed, so the body sees the (1, 1, page, dh) block.
     kv_spec = pl.BlockSpec(
-        (1, 1, page, dh),
-        lambda g, s, *pf: (pf[4][g // kvh, s], g % kvh, 0, 0))
+        (pl.squeezed, 1, 1, page, dh),
+        lambda g, s, *pf: (pf[5][0], pf[4][g // kvh, s], g % kvh, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda g, s, *_: (g, 0, 0)),
@@ -309,7 +312,8 @@ def flash_decode_call(q, k_pages, v_pages, inj_idx, inj_mag, rng, lengths,
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
         ),
         interpret=interpret,
-    )(inj_idx, inj_mag, rng, lengths, page_table, q, k_pages, v_pages)
+    )(inj_idx, inj_mag, rng, lengths, page_table, layer, q, k_pages,
+      v_pages)
     return out, _squeeze_report(rep)
 
 

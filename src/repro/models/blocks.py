@@ -517,21 +517,24 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, lengths: jax.Array,
-                           page_table: jax.Array, ctx: Ctx) -> jax.Array:
-    """Single-position attention against one layer of a *paged* KV cache
-    (train/kv_cache.py). q: (B, 1, H, dh); k_pages, v_pages: (P, KVH, page,
-    dh) page pools; lengths: int32 (B,) true kv lengths; page_table: int32
-    (B, max_pages) pool-page ids per slot (NULL-padded).
+                           page_table: jax.Array, layer, ctx: Ctx
+                           ) -> jax.Array:
+    """Single-position attention against layer ``layer`` of a *paged* KV
+    cache (train/kv_cache.py). q: (B, 1, H, dh); k_pages, v_pages: (L, P,
+    KVH, page, dh) stacked page pools; lengths: int32 (B,) true kv lengths;
+    page_table: int32 (B, max_pages) pool-page ids per slot (NULL-padded);
+    layer: int or int32 scalar.
 
     On the pallas FT backend this is ONE `kernels.flashft` decode launch:
-    the page table is scalar-prefetched and consumed by the K/V index maps
-    (each grid step streams exactly one pool page — no dense gather, no
-    padding traffic), the per-slot ragged lengths ride a prefetched int32
-    vector, and both in-kernel GEMMs carry the checksum verify with the
-    kv-span clamp folded into the PV tolerance. Recorded as one fused
-    telemetry site, "dec_flash". Elsewhere (and under
-    ``ctx.attn_impl="chunked"``) the pages are gathered back to the dense
-    (B, S, KVH, dh) layout and `decode_attention` runs as the oracle,
+    the page table and the layer are scalar-prefetched and consumed by the
+    K/V index maps (each grid step streams exactly one pool page — no dense
+    gather, no padding traffic, no copy of the layer), the per-slot ragged
+    lengths ride a prefetched int32 vector, and both in-kernel GEMMs carry
+    the checksum verify with the kv-span clamp folded into the PV
+    tolerance. Recorded as one fused telemetry site, "dec_flash".
+    Elsewhere (and under ``ctx.attn_impl="chunked"``) the layer's pages
+    are gathered back to the dense (B, S, KVH, dh) layout and
+    `decode_attention` runs as the oracle,
     recording under its own "dec_page_qk"/"dec_page_pv" labels (the paged
     cache GEMMs are a different population than the dense decode path —
     the planner prices them separately)."""
@@ -543,13 +546,13 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                        or (ft.enabled and ft.backend == "pallas")))
     if use_kernel:
         from repro.kernels import ops as kops
-        kvh = k_pages.shape[1]
+        kvh = k_pages.shape[2]
         note_site("dec_flash", "flash", h // kvh,
-                  page_table.shape[1] * k_pages.shape[2], dh,
+                  page_table.shape[1] * k_pages.shape[3], dh,
                   batch=b * kvh, in_bytes=jnp.dtype(q.dtype).itemsize)
         fkey = ctx.key if ctx.site_allowed("dec_flash") else None
         out, rep = kops.flash_ft_decode(q[:, 0], k_pages, v_pages, lengths,
-                                        page_table, ft=ft, key=fkey)
+                                        page_table, layer, ft=ft, key=fkey)
         scope = telemetry.current_scope()
         if scope is not None:
             det = jnp.sum(rep[..., 0]).astype(jnp.int32)
@@ -557,8 +560,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             scope.record_summary(det, maxres, ft.corrects, site="dec_flash")
         return out[:, None]
     from repro.train import kv_cache as _kvc
-    kd = _kvc.gather_layer(k_pages, page_table)
-    vd = _kvc.gather_layer(v_pages, page_table)
+    kd = _kvc.gather_layer(k_pages[layer], page_table)
+    vd = _kvc.gather_layer(v_pages[layer], page_table)
     return decode_attention(q, kd, vd, lengths, ctx, site_prefix="dec_page")
 
 

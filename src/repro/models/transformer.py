@@ -265,30 +265,36 @@ def paged_decode_step(params, token: jax.Array, cache: Dict[str, Any],
     "page_table": int32 (B, max_pages), "length": int32 (B,)}. Returns
     (logits (B, 1, V), new cache).
 
-    The new kv lands via a per-layer page-table-routed scatter
-    (`kv_cache.append_layer`) and attention runs through
+    The whole pool rides the layer scan's carry, so it is updated where it
+    lies: the scan's xs are only the layer params and index, and no layer
+    of the pool is ever sliced out or stacked back (with the cache donated,
+    the step writes one token per slot and layer, and copies nothing). The
+    new kv lands via `kv_cache.append_layer` (one in-place
+    dynamic-update-slice per slot) and attention runs through
     `blocks.paged_decode_attention` — on the pallas FT backend one flashft
-    decode launch per layer with prefetched ragged lengths, so thousands
-    of slots share the pool with zero dense padding. Dead slots (all-NULL
-    table rows, length 0) scatter into the reserved null page and produce
-    ignored garbage logits; the engine rebuilds `page_table`/`length` from
-    the host allocator each step."""
+    decode launch per layer that reads the layer in place through
+    prefetched page-table and layer indices, with ragged lengths, so
+    thousands of slots share the pool with zero dense padding. Dead slots
+    (all-NULL table rows, length 0) write into the reserved null page and
+    produce ignored garbage logits; the engine rebuilds
+    `page_table`/`length` from the host allocator each step."""
     from repro.train import kv_cache as kv_cache_lib
     x = blocks.embed(token, params["embed"]["table"]).astype(ctx.dtype)
     pos = cache["length"]                                  # (B,)
     table = cache["page_table"]
+    n_pages = cache["k_pages"].shape[1]
 
-    def layer_fn(lp, h, scanned_cache):
-        k_p, v_p, idx = scanned_cache
+    def layer_fn(lp, h, k_all, v_all, idx):
         lctx = ctx.fold(idx)
         hn = blocks.rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
         q, k_new, v_new = _project_qkv(lp["attn"], hn, cfg, lctx,
                                        pos[:, None])
         b = h.shape[0]
-        k_p = kv_cache_lib.append_layer(k_p, k_new[:, 0], table, pos)
-        v_p = kv_cache_lib.append_layer(v_p, v_new[:, 0], table, pos)
-        att = blocks.paged_decode_attention(q, k_p, v_p, pos + 1, table,
-                                            lctx)
+        rows = kv_cache_lib.layer_table(table, idx, n_pages)
+        k_all = kv_cache_lib.append_layer(k_all, k_new[:, 0], rows, pos)
+        v_all = kv_cache_lib.append_layer(v_all, v_new[:, 0], rows, pos)
+        att = blocks.paged_decode_attention(q, k_all, v_all, pos + 1, table,
+                                            idx, lctx)
         h = h + lctx.dot("wo", att.reshape(b, 1, -1), lp["attn"]["wo"])
         hn = blocks.rmsnorm(h, lp["ffn_norm"], cfg.norm_eps)
         if cfg.moe is not None:
@@ -298,7 +304,7 @@ def paged_decode_step(params, token: jax.Array, cache: Dict[str, Any],
             h = h + y
         else:
             h = h + blocks.mlp(lp["mlp"], hn, lctx)
-        return h, (k_p, v_p)
+        return h, k_all, v_all
 
     # Same serve-path telemetry gate as decode_step: per-layer scoping only
     # when the caller opened an ft_scope (resolved at trace time).
@@ -306,20 +312,20 @@ def paged_decode_step(params, token: jax.Array, cache: Dict[str, Any],
     n = cfg.n_layers
 
     def body(carry, scanned):
-        h, rep = carry
-        lp, k_p, v_p, idx = scanned
+        h, rep, k_all, v_all = carry
+        lp, idx = scanned
         if want_ft:
-            (h, (k_p, v_p)), rep_l = telemetry.scoped(
-                lambda: layer_fn(lp, h, (k_p, v_p, idx)))
+            (h, k_all, v_all), rep_l = telemetry.scoped(
+                lambda: layer_fn(lp, h, k_all, v_all, idx))
             rep = rep.merge_at(rep_l, idx + 1)
         else:
-            h, (k_p, v_p) = layer_fn(lp, h, (k_p, v_p, idx))
-        return (h, rep), (k_p, v_p)
+            h, k_all, v_all = layer_fn(lp, h, k_all, v_all, idx)
+        return (h, rep, k_all, v_all), None
 
-    (x, rep), (new_k, new_v) = loops.scan(
-        body, (x, telemetry.FTReport.empty(rows=n + 1)),
-        (params["layers"], cache["k_pages"], cache["v_pages"],
-         jnp.arange(n)))
+    (x, rep, new_k, new_v), _ = loops.scan(
+        body, (x, telemetry.FTReport.empty(rows=n + 1), cache["k_pages"],
+               cache["v_pages"]),
+        (params["layers"], jnp.arange(n)))
     if want_ft:
         telemetry.record_report(rep)
     x = blocks.rmsnorm(x, params["final_norm"], cfg.norm_eps)

@@ -287,19 +287,36 @@ def write_prefill(cache: Dict[str, Any], slot, table_row: jax.Array,
 
 def append_layer(pages: jax.Array, kv_new: jax.Array, table: jax.Array,
                  pos: jax.Array) -> jax.Array:
-    """Write one token's K (or V) for every slot into ONE layer's pool.
-    pages (P, KVH, page, dh); kv_new (B, KVH, dh); table (B, MP);
-    pos int32[B] — the target position (the slot's current length). Dead
-    slots (all-NULL rows) scatter into the trash page."""
-    page = pages.shape[2]
-    mp = table.shape[1]
-    b = table.shape[0]
+    """Write one token's K (or V) for every slot into the pool, in place.
+    pages (..., KVH, page, dh): one layer's pool (P, KVH, page, dh), or the
+    stacked pool (L, P, KVH, page, dh) with ``table`` holding ids into its
+    flattened (layer, page) axes (`layer_table`); kv_new (B, KVH, dh);
+    table (B, MP); pos int32[B] — the target position (the slot's current
+    length). One dynamic-update-slice per slot: XLA performs it in place on
+    a donated or loop-carried pool, with no change of layout. Dead slots
+    (all-NULL rows) write into the trash page; live slots never share a
+    page, so the order of the writes does not matter."""
+    lead = pages.shape[:-3]
+    kvh, page, dh = pages.shape[-3:]
+    b, mp = table.shape
     pidx = jnp.minimum(pos // page, mp - 1)
     target = table[jnp.arange(b), pidx]                    # (B,)
     offs = pos % page
-    # Advanced indices on dims (0: page id, 2: in-page offset) around the
-    # kv-head slice → the value carries (B, KVH, dh).
-    return pages.at[target, :, offs].set(kv_new.astype(pages.dtype))
+    kv_new = kv_new.astype(pages.dtype).reshape(
+        (b,) + (1,) * len(lead) + (kvh, 1, dh))
+    for i in range(b):
+        where = jnp.unravel_index(target[i], lead)
+        pages = jax.lax.dynamic_update_slice(
+            pages, kv_new[i], (*where, 0, offs[i], 0))
+    return pages
+
+
+def layer_table(table: jax.Array, layer, n_pages: int) -> jax.Array:
+    """``table``'s page ids as ids into the stacked pool's flattened
+    (layer, page) axes at ``layer`` — page p of layer l is l·n_pages + p —
+    the form `append_layer` takes to write one layer of the stacked pool
+    where it lies."""
+    return table + layer * n_pages
 
 
 def append_token(cache: Dict[str, Any], k_new: jax.Array, v_new: jax.Array

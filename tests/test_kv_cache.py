@@ -276,6 +276,31 @@ def test_append_layer_dead_slot_hits_trash_page():
     assert float(np.abs(trash).max()) == 7.0
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_append_layer_writes_only_its_layer(layer):
+    """On a stacked 3-layer pool, `append_layer` through `layer_table`
+    writes the live slot's token into layer ``layer`` alone, the dead
+    (all-NULL) slot's into that layer's trash page, and leaves every other
+    element of the pool bitwise unchanged."""
+    n_l, n_pages, slot_len = 3, 1 + 2 * _MP, _PAGE + 2
+    alloc = kvc.PageAllocator(n_pages, 2, _MP, _PAGE)
+    slot, _ = alloc.alloc_slot(slot_len + 1)
+    rng = np.random.default_rng(layer)
+    pool = rng.standard_normal((n_l, n_pages, _KVH, _PAGE, _DH)
+                               ).astype(np.float32)
+    new = rng.standard_normal((2, _KVH, _DH)).astype(np.float32)
+    pos = np.asarray([slot_len, 0], np.int32)
+    out = jax.jit(lambda p, l: kvc.append_layer(
+        p, jnp.asarray(new),
+        kvc.layer_table(jnp.asarray(alloc.page_table), l, n_pages),
+        jnp.asarray(pos)))(jnp.asarray(pool), layer)
+    want = pool.copy()
+    page = alloc.page_table[slot, slot_len // _PAGE]
+    want[layer, page, :, slot_len % _PAGE] = new[slot]
+    want[layer, kvc.NULL_PAGE, :, 0] = new[1]
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
 def test_plan_pages_geometry():
     from repro.configs.base import ModelConfig
     from repro.core.policy import ONLINE_BLOCK

@@ -87,8 +87,8 @@ def test_paged_ragged_decode_matches_oracle(kvh, nrep, lengths):
                                   seed=kvh * 10 + nrep)
     q = jnp.asarray(rng.standard_normal((len(lengths), h, dh)), jnp.float32)
     out, rep = ops.flash_ft_decode(
-        q, cache["k_pages"][0], cache["v_pages"][0],
-        jnp.asarray(alloc.lengths), jnp.asarray(alloc.page_table),
+        q, cache["k_pages"], cache["v_pages"],
+        jnp.asarray(alloc.lengths), jnp.asarray(alloc.page_table), 0,
         ft=FTConfig(level="block", action="correct"), interpret=True)
     out = np.asarray(out)
     assert float(np.asarray(rep)[..., 0].sum()) == 0.0, "false positive"
@@ -103,24 +103,25 @@ def test_paged_ragged_decode_matches_oracle(kvh, nrep, lengths):
                                        rtol=2e-5)
 
 
-def _exact_paged_kv(lengths, kvh, dh, page, seed=0):
+def _exact_paged_kv(lengths, kvh, dh, page, seed=0, n_layers=1):
     """Exactly-representable operands: one-hot 64·e_t queries/keys (matched
     score 256 → softmax weights in {1, 1/2} exactly, dh=256 scale is 2^-4),
     small-integer V — the paged decode output is exact in f32, so a
-    corrected SEU must be bit-for-bit identical to the clean run."""
+    corrected SEU must be bit-for-bit identical to the clean run. Each
+    layer of an ``n_layers`` pool gets its own V."""
     rng = np.random.default_rng(seed)
     b = len(lengths)
     mp = 512 // page
     n_pages = 1 + b * mp
-    cache = kvc.init_paged_cache(1, n_pages, b, mp, kvh, page, dh,
+    cache = kvc.init_paged_cache(n_layers, n_pages, b, mp, kvh, page, dh,
                                  jnp.float32)
     alloc = kvc.PageAllocator(n_pages, b, mp, page)
     for length in lengths:
         s, _ = alloc.alloc_slot(length)
         karr = 64.0 * np.eye(dh, dtype=np.float32)[np.arange(length) % dh]
         ks = jnp.asarray(np.broadcast_to(karr[None, :, None],
-                                         (1, length, kvh, dh)).copy())
-        vs = jnp.asarray(rng.integers(-2, 3, (1, length, kvh, dh)),
+                                         (n_layers, length, kvh, dh)).copy())
+        vs = jnp.asarray(rng.integers(-2, 3, (n_layers, length, kvh, dh)),
                          jnp.float32)
         cache = kvc.write_prefill(cache, s, jnp.asarray(alloc.page_table[s]),
                                   ks, vs, length)
@@ -129,12 +130,17 @@ def _exact_paged_kv(lengths, kvh, dh, page, seed=0):
     return q, cache, alloc
 
 
-def test_paged_decode_seu_corrected_bitexact():
+@pytest.mark.parametrize("n_layers,layer", [(1, 0), (3, 2)])
+def test_paged_decode_seu_corrected_bitexact(n_layers, layer):
+    """An SEU at one (slot, head) grid row of one layer is detected on that
+    row and corrected bit for bit, in a single-layer pool and in layer 2 of
+    a stacked one."""
     kvh, dh, page = 2, 256, 16
-    q, cache, alloc = _exact_paged_kv([272, 320], kvh, dh, page)
+    q, cache, alloc = _exact_paged_kv([272, 320], kvh, dh, page,
+                                      n_layers=n_layers)
     ft = FTConfig(level="block", action="correct")
-    args = (q, cache["k_pages"][0], cache["v_pages"][0],
-            jnp.asarray(alloc.lengths), jnp.asarray(alloc.page_table))
+    args = (q, cache["k_pages"], cache["v_pages"],
+            jnp.asarray(alloc.lengths), jnp.asarray(alloc.page_table), layer)
     clean, _ = ops.flash_ft_decode(*args, ft=ft, interpret=True)
     spec = InjectionSpec(row=1, col=7, k_step=1, magnitude=777.0)
     g = 1 * kvh + 0                       # grid row: slot 1, kv head 0
@@ -152,8 +158,8 @@ def test_paged_decode_seu_corrected_bitexact():
 def test_paged_decode_seu_detect_only_leaves_error():
     kvh, dh, page = 2, 256, 16
     q, cache, alloc = _exact_paged_kv([272, 320], kvh, dh, page)
-    args = (q, cache["k_pages"][0], cache["v_pages"][0],
-            jnp.asarray(alloc.lengths), jnp.asarray(alloc.page_table))
+    args = (q, cache["k_pages"], cache["v_pages"],
+            jnp.asarray(alloc.lengths), jnp.asarray(alloc.page_table), 0)
     clean, _ = ops.flash_ft_decode(
         *args, ft=FTConfig(level="block", action="correct"), interpret=True)
     # inject at the LAST live kv step of slot 1 (len 320 → 20 pages) so the
@@ -172,11 +178,39 @@ def test_paged_decode_seu_detect_only_leaves_error():
 def test_flash_ft_decode_rejects_unaligned_head_dim():
     with pytest.raises(ValueError):
         ops.flash_ft_decode(jnp.zeros((1, 2, 64)),
-                            jnp.zeros((2, 1, 16, 64)),
-                            jnp.zeros((2, 1, 16, 64)),
+                            jnp.zeros((1, 2, 1, 16, 64)),
+                            jnp.zeros((1, 2, 1, 16, 64)),
                             jnp.zeros((1,), jnp.int32),
-                            jnp.zeros((1, 1), jnp.int32),
+                            jnp.zeros((1, 1), jnp.int32), 0,
                             ft=FT_PALLAS)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_decode_reads_layer_of_stacked_pool(layer):
+    """The kernel on layer ``layer`` of a 3-layer pool (a traced index, as
+    the layer scan passes it) gives bitwise the output and report of the
+    same call on that layer alone as a one-layer pool."""
+    lengths, kvh, nrep, dh, page, mp = [17, 64, 0], 2, 2, 128, 16, 4
+    rng = np.random.default_rng(layer)
+    n_pages = 1 + len(lengths) * mp
+    alloc = kvc.PageAllocator(n_pages, len(lengths), mp, page)
+    for length in lengths:
+        alloc.alloc_slot(length)
+    shape = (3, n_pages, kvh, page, dh)
+    k_all = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v_all = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((len(lengths), kvh * nrep, dh)),
+                    jnp.float32)
+    lens, table = jnp.asarray(alloc.lengths), jnp.asarray(alloc.page_table)
+    ft = FTConfig(level="block", action="correct")
+    out, rep = jax.jit(lambda l: ops.flash_ft_decode(
+        q, k_all, v_all, lens, table, l, ft=ft, interpret=True))(layer)
+    one, rep_one = ops.flash_ft_decode(q, k_all[layer][None],
+                                       v_all[layer][None], lens, table, 0,
+                                       ft=ft, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
+    np.testing.assert_array_equal(np.asarray(rep), np.asarray(rep_one))
+    assert float(np.asarray(rep)[..., 0].sum()) == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -9,11 +9,13 @@ holds a TPU custom call and that its lowering names the expected kernel.
 The topology is described inside a fixture, never at import: only the test
 worker that runs this file loads the TPU library.
 """
+import dataclasses
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -80,9 +82,9 @@ def _flash_dkv(*args):
     return dk, dv, rep_dkv
 
 
-def _decode(q, k_pages, v_pages, lengths, table):
-    return ops.flash_ft_decode(q, k_pages, v_pages, lengths, table, ft=FT,
-                               interpret=False)
+def _decode(q, k_pages, v_pages, lengths, table, layer):
+    return ops.flash_ft_decode(q, k_pages, v_pages, lengths, table, layer,
+                               ft=FT, interpret=False)
 
 
 def _batched(a, b):
@@ -117,9 +119,10 @@ CASES = {
     "flash_dq": (_flash_dq, list(_FLASH_BWD_ARGS), "_flash_dq_kernel"),
     "flash_dkv": (_flash_dkv, list(_FLASH_BWD_ARGS),
                   "_flash_dkv_kernel"),
-    "paged_decode": (_decode, [(8, H, DH, BF16), (64, KVH, 128, DH, BF16),
-                               (64, KVH, 128, DH, BF16), (8, I32),
-                               (8, 16, I32)], "_flash_decode_kernel"),
+    # a stacked 2-layer pool, read at a traced layer index
+    "paged_decode": (_decode, [(8, H, DH, BF16), (2, 64, KVH, 128, DH, BF16),
+                               (2, 64, KVH, 128, DH, BF16), (8, I32),
+                               (8, 16, I32), (I32,)], "_flash_decode_kernel"),
     # the chunked-attention QK GEMM: (B·heads, Sq, dh) x (B·heads, dh, Skv)
     "batched": (_batched, [(64, 512, DH, BF16), (64, DH, S, BF16)],
                 "gemm_block_batched"),
@@ -140,3 +143,80 @@ def test_ft_kernel_compiles_for_v5e(one_chip, case):
     names = set(re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()))
     assert any(n.startswith(kernel) for n in names), (kernel, names)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+# The serving cell's decode geometry: 10 slots, max_len 2560, 512-token
+# pages, so 5 pages a slot and 51 in the pool with the null page.
+SLOTS, MAX_PAGES, PAGE = 10, 5, 512
+POOL_PAGES = 1 + SLOTS * MAX_PAGES
+#: ops that only name or pass a buffer, and may hold the whole pool
+_NAMING_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def _instructions(hlo: str):
+    """{name: (opcode, shape, operand names)} of every array-valued
+    instruction of a compiled module's text."""
+    pat = r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)"
+    return {name: (opcode, [int(d) for d in shape.split(",") if d],
+                   re.findall(r"%([\w.\-]+)", operands))
+            for name, shape, opcode, operands in re.findall(pat, hlo)}
+
+
+def test_paged_decode_step_keeps_pool_in_place(one_chip, monkeypatch):
+    """The serving engine's decode step, compiled for the chip with Mosaic
+    kernels at phi4-mini widths (2 layers) and the serving cell's pool,
+    moves no layer of the K/V page pool: every instruction whose result
+    holds a layer of the pool (all of its sizes among its dimensions, so
+    in any layout) only names or passes the buffer, or is a
+    dynamic-update-slice that writes a small update into it in place — no
+    copy, slice, scatter, fusion or allocation of one — and the donated
+    pools are aliased to the step's outputs."""
+    from repro.configs import registry
+    from repro.models import transformer as tfm
+    from repro.models.blocks import Ctx
+
+    monkeypatch.setattr(ops, "_should_interpret",
+                        lambda interpret=None: False)
+    cfg = dataclasses.replace(registry.get_config("phi4-mini-3.8b"),
+                              n_layers=2, tie_embeddings=True)
+    ctx = Ctx(ft=FT, key=None, dtype=BF16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda k: tfm.init(cfg, k, BF16),
+                       jax.random.PRNGKey(0)))
+    pool = (cfg.n_layers, POOL_PAGES, cfg.n_kv_heads, PAGE, cfg.head_dim)
+    cache = {"k_pages": sds(pool, BF16), "v_pages": sds(pool, BF16),
+             "page_table": sds((SLOTS, MAX_PAGES), I32),
+             "length": sds((SLOTS,), I32)}
+    step = jax.jit(lambda p, t, c: tfm.paged_decode_step(p, t, c, cfg, ctx),
+                   donate_argnums=(2,))
+    hlo = step.lower(params, sds((SLOTS, 1), I32), cache).compile().as_text()
+
+    layer = pool[1:]
+    ins = _instructions(hlo)
+    moved = []
+    for name, (opcode, shape, operands) in ins.items():
+        if (np.prod(shape) < np.prod(layer)
+                or any(shape.count(d) < layer.count(d) for d in layer)
+                or opcode in _NAMING_OPS):
+            continue
+        if opcode == "dynamic-update-slice":
+            update = ins[operands[1]][1]
+            if np.prod(update) < np.prod(layer):
+                continue
+        moved.append((name, opcode, shape))
+    assert not moved, moved
+
+    # Donated cache leaves alias the new cache's. Flat argument order is
+    # params, token, then the cache's keys sorted; outputs are the logits,
+    # then the new cache's keys sorted.
+    n_params = len(jax.tree.leaves(params))
+    keys = sorted(cache)
+    alias = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}", hlo))
+    for key in ("k_pages", "v_pages"):
+        out_i, arg_i = 1 + keys.index(key), n_params + 1 + keys.index(key)
+        assert alias.get(str(out_i)) == str(arg_i), (key, alias)
